@@ -34,6 +34,7 @@ import numpy as np
 from benchmarks.common import emit
 
 from repro.kernels import ragged_attention as RA
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import attention_core
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -171,6 +172,7 @@ def main():
                     help="tiny shapes for CI (seconds, asserts parity)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     run(smoke=args.smoke, seed=args.seed)
 
 

@@ -1,16 +1,15 @@
-"""Kernel micro-benchmarks: Pallas (interpret-mode, correctness-bound on
-CPU) and the jnp reference paths (the actual CPU compute numbers).
+"""Kernel micro-benchmarks: Pallas kernels and the jnp reference paths.
 
-On real TPU hardware the pallas_call timings replace the interpret
-numbers; here `us_per_call` for *_interp rows measures the Python
-interpreter loop and is reported for completeness only (derived column
-carries the analytic FLOPs)."""
+On a TPU the Pallas rows time the compiled kernels (``*_compiled``); on
+the CPU they run in interpret mode (``*_interp``) and `us_per_call`
+measures the Python interpreter loop, reported for completeness only
+(derived column carries the analytic FLOPs)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, resolve_interpret
 from repro.quant import hqq
 
 from benchmarks.common import emit, timeit
@@ -18,6 +17,10 @@ from benchmarks.common import emit, timeit
 
 def run(quick=False):
     rows = []
+    if resolve_interpret():
+        mode, how = "interp", "interpret-mode (CPU emulation)"
+    else:
+        mode, how = "compiled", f"compiled ({jax.devices()[0].device_kind})"
     # --- dequant matmul ---
     M, K, N = (32, 256, 128) if quick else (64, 1024, 512)
     w = jax.random.normal(jax.random.key(0), (K, N)) * 0.05
@@ -36,11 +39,10 @@ def run(quick=False):
                      "derived": f"gflops={flops/us/1e3:.2f}"})
         if not quick:
             us_k, _ = timeit(
-                lambda xx: ops.dequant_matmul(xx, qt, interpret=True), x,
+                lambda xx: ops.dequant_matmul(xx, qt), x,
                 warmup=1, iters=1)
-            rows.append({"name": f"dequant_matmul_pallas_{bits}bit_interp",
-                         "us_per_call": f"{us_k:.0f}",
-                         "derived": "interpret-mode (CPU emulation)"})
+            rows.append({"name": f"dequant_matmul_pallas_{bits}bit_{mode}",
+                         "us_per_call": f"{us_k:.0f}", "derived": how})
 
     # --- flash attention ---
     BH, BKV, S, d = (4, 2, 256, 64) if quick else (8, 2, 1024, 64)
@@ -58,9 +60,8 @@ def run(quick=False):
         us_k, _ = timeit(
             lambda a, b, c: ops.flash_attention(a, b, c, causal=True),
             q, k, v, warmup=1, iters=1)
-        rows.append({"name": "flash_attention_pallas_interp",
-                     "us_per_call": f"{us_k:.0f}",
-                     "derived": "interpret-mode (CPU emulation)"})
+        rows.append({"name": f"flash_attention_pallas_{mode}",
+                     "us_per_call": f"{us_k:.0f}", "derived": how})
 
     # --- model-level chunked attention (production jnp path) ---
     from repro.models.layers import attention_core
@@ -87,4 +88,7 @@ if __name__ == "__main__":
                     dest="smoke",
                     help="reduced shapes, jnp reference paths only "
                          "(CI smoke)")
-    run(quick=ap.parse_args().smoke)
+    args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    run(quick=args.smoke)

@@ -50,6 +50,7 @@ from benchmarks.common import emit
 from repro.configs import get_config
 from repro.core.offload_engine import (OffloadEngine, generate_plain,
                                        quantize_for_offload)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -264,6 +265,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     run(smoke=args.smoke, trained=args.trained, max_new=args.max_new,
         seed=args.seed)
 

@@ -25,6 +25,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (fig2_lru, fig2_spec, kernels_bench, serve_bench,
                             table1_quant, table2_speed)
 

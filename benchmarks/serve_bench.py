@@ -48,6 +48,7 @@ import numpy as np
 from benchmarks.common import emit
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.engine import ContinuousEngine, Request, ServeEngine
 from repro.serving.sampler import SamplerConfig
@@ -818,6 +819,7 @@ def main():
     ap.add_argument("--overlap", action="store_true",
                     help="use the expert-overlap admission policy")
     args = ap.parse_args()
+    enable_compile_cache()
     run(quick=args.smoke, trained=args.trained, n_requests=args.n_requests,
         max_slots=args.max_slots, slot_len=args.slot_len, seed=args.seed,
         overlap=args.overlap, prefill_chunk=args.prefill_chunk)

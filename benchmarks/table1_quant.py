@@ -119,4 +119,6 @@ def _restore_attn(qtree, orig):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

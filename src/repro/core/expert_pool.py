@@ -7,9 +7,11 @@ Expert weights are HQQ-quantized once and then *stay packed*:
   (``w_gate``/``w_up``/``w_down``).  The same container describes all three
   residency tiers, distinguished only by its leading axes:
 
-  - **host store**  ``(L_moe, E, ...)`` — every expert of every MoE layer,
-    host-resident (on TPU: pinned host memory; on this CPU host: plain
-    arrays).  Never dequantized as a whole.
+  - **host store**  ``(L_moe, E, ...)`` — every expert of every MoE layer.
+    Today a device-resident array on every backend (no ``memory_kind``
+    placement: moving it to pinned host memory is ROADMAP R1), so the h2d
+    transfers the counters report are computed, not moved.  Never
+    dequantized as a whole.
   - **LRU pool**    ``(L_moe, cache_size, ...)`` — the per-layer device
     buffer pool of ``k`` expert slots the paper keeps resident.
   - **staging**     ``(L_moe, num_speculative, ...)`` — the speculative

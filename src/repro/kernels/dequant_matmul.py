@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.quant.hqq import unpack_codes
 
 
@@ -45,7 +46,10 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, o_ref, *, bits, group_size):
     packed = p_ref[...]  # (bk//g, g*bits//8, bn)
     scale = s_ref[...].astype(jnp.float32)  # (bk//g, 1, bn)
     zero = z_ref[...].astype(jnp.float32)
-    q = unpack_codes(packed, bits, group_size).astype(jnp.float32)
+    # widen before unpacking: Mosaic has no uint8 -> f32 cast, and the
+    # shifts/masks are exact in int32 (the store keeps the uint8 format)
+    q = unpack_codes(packed.astype(jnp.int32), bits,
+                     group_size).astype(jnp.float32)
     w = (q - zero) * scale  # (bk//g, g, bn)
     w = w.reshape(x.shape[1], -1)  # (bk, bn)
     o_ref[...] += jnp.dot(x.astype(jnp.float32), w,
@@ -55,7 +59,7 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, o_ref, *, bits, group_size):
 @functools.partial(jax.jit, static_argnames=("bits", "group_size", "bm",
                                              "bn", "bk", "interpret"))
 def dequant_matmul_pallas(x, packed, scale, zero, *, bits, group_size,
-                          bm=128, bn=128, bk=128, interpret=True):
+                          bm=128, bn=128, bk=128, interpret=None):
     """x: (M, K) @ packed W (G, g*bits//8, N) -> (M, N) f32."""
     M, K = x.shape
     G, pg, N = packed.shape
@@ -79,7 +83,7 @@ def dequant_matmul_pallas(x, packed, scale, zero, *, bits, group_size,
             pl.BlockSpec((gb, 1, bn), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, packed, scale, zero)
 
 
@@ -98,7 +102,8 @@ def _batched_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, *, bits, group_size):
     packed = p_ref[0]  # (gb, g*bits//8, bn)
     scale = s_ref[0].astype(jnp.float32)
     zero = z_ref[0].astype(jnp.float32)
-    q = unpack_codes(packed, bits, group_size).astype(jnp.float32)
+    q = unpack_codes(packed.astype(jnp.int32), bits,
+                     group_size).astype(jnp.float32)
     w = ((q - zero) * scale).reshape(x.shape[1], -1)  # (bk, bn)
     o_ref[0] += jnp.dot(x.astype(jnp.float32), w,
                         preferred_element_type=jnp.float32)
@@ -108,7 +113,7 @@ def _batched_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, *, bits, group_size):
                                              "bn", "bk", "interpret"))
 def dequant_matmul_batched_pallas(x, packed, scale, zero, *, bits,
                                   group_size, bm=128, bn=128, bk=128,
-                                  interpret=True):
+                                  interpret=None):
     """x (B, M, K) @ per-row packed W (B, G, g*bits//8, N) -> (B, M, N)."""
     B, M, K = x.shape
     _, G, pg, N = packed.shape
@@ -129,7 +134,7 @@ def dequant_matmul_batched_pallas(x, packed, scale, zero, *, bits,
             pl.BlockSpec((1, gb, 1, bn), lambda b, i, j, k: (b, k, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j, k: (b, i, j)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, packed, scale, zero)
 
 
@@ -144,7 +149,7 @@ def _slots_kernel(slots_ref, x_ref, p_ref, s_ref, z_ref, o_ref, *, bits,
                                              "bn", "bk", "interpret"))
 def dequant_matmul_slots_pallas(x, packed, scale, zero, slots, *, bits,
                                 group_size, bm=128, bn=128, bk=128,
-                                interpret=True):
+                                interpret=None):
     """x (B, M, K) @ dequant(W[slots[b]]) -> (B, M, N) where the packed
     weight tier W (S, G, g*bits//8, N) stays whole: ``slots`` (B,) int32
     rides in as a scalar-prefetch argument and the *index maps* pick each
@@ -179,5 +184,5 @@ def dequant_matmul_slots_pallas(x, packed, scale, zero, slots, *, bits,
         functools.partial(_slots_kernel, bits=bits, group_size=group_size),
         out_shape=jax.ShapeDtypeStruct((B, M, N), jnp.float32),
         grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(slots.astype(jnp.int32), x, packed, scale, zero)

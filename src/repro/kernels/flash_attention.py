@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -90,7 +92,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "q_offset", "interpret",
                                              "skip_window_blocks"))
 def flash_attention_pallas(q, k, v, *, causal=True, window=None, bq=128,
-                           bk=128, q_offset=0, interpret=True,
+                           bk=128, q_offset=0, interpret=None,
                            skip_window_blocks=True):
     """q: (BH, Sq, d); k, v: (BKV, Skv, d), BH = BKV * G. -> (BH, Sq, d).
 
@@ -142,5 +144,5 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, bq=128,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

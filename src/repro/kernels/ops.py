@@ -4,8 +4,9 @@ to the pure-jnp oracle for shapes/bitwidths the kernels don't tile
 
 ``dequant_matmul`` is the dispatch point for packed-offloaded MoE
 execution (``models/moe.moe_apply_packed``, DESIGN.md §6): batch-1 decode
-and 3-bit codes take the reference path on this host; MXU-aligned 2/4/8-
-bit shapes take the fused Pallas kernel."""
+and 3-bit codes take the reference path on every backend; MXU-aligned
+2/4/8-bit shapes take the fused Pallas kernel — compiled on a TPU,
+interpreted on the CPU (``repro.kernels.resolve_interpret``)."""
 from __future__ import annotations
 
 from typing import Optional
@@ -22,7 +23,7 @@ from repro.quant.hqq import QTensor, _meta_dequantize, unpack_codes
 KERNEL_BITS = (2, 4, 8)
 
 
-def dequant_matmul(x, qt: QTensor, *, interpret=True, use_kernel=True):
+def dequant_matmul(x, qt: QTensor, *, use_kernel=True):
     """x (M, K) @ dequant(qt) where qt quantizes a (K, N) weight."""
     assert len(qt.shape) == 2, "2-D weights (reshape heads first)"
     scale, zero = _meta_dequantize(qt)
@@ -32,7 +33,7 @@ def dequant_matmul(x, qt: QTensor, *, interpret=True, use_kernel=True):
         bm = 128 if M % 128 == 0 else 8
         return dequant_matmul_pallas(
             x, qt.packed, scale, zero, bits=qt.bits,
-            group_size=qt.group_size, bm=bm, interpret=interpret)
+            group_size=qt.group_size, bm=bm)
     return ref.dequant_matmul_ref(x, qt.packed, scale, zero, bits=qt.bits,
                                   group_size=qt.group_size)
 
@@ -52,8 +53,7 @@ def _dequant_rows(qt_stacked: QTensor, scale, zero):
     return w.reshape(B, G * qt_stacked.group_size, N)
 
 
-def dequant_matmul_batched(x, qt: QTensor, *, interpret=True,
-                           use_kernel=True):
+def dequant_matmul_batched(x, qt: QTensor, *, use_kernel=True):
     """x (B, M, K) @ dequant(qt[b]) per row, qt stacked (B, K, N) packed.
 
     ONE dispatch covers the whole batch of per-(token, k) expert matmuls
@@ -69,13 +69,12 @@ def dequant_matmul_batched(x, qt: QTensor, *, interpret=True,
         bm = 128 if M % 128 == 0 else 8
         return dequant_matmul_batched_pallas(
             x, qt.packed, scale, zero, bits=qt.bits,
-            group_size=qt.group_size, bm=bm, interpret=interpret)
+            group_size=qt.group_size, bm=bm)
     w = _dequant_rows(qt, scale, zero)
     return jnp.einsum("bmk,bkn->bmn", x.astype(jnp.float32), w)
 
 
-def dequant_matmul_slots(x, qt: QTensor, slots, *, interpret=True,
-                         use_kernel=True):
+def dequant_matmul_slots(x, qt: QTensor, slots, *, use_kernel=True):
     """x (B, M, K) @ dequant(qt[slots[b]]): serve a batch of matmuls by
     *slot index* into a stacked packed tier (S, K, N) without gathering
     it — the Pallas kernel reads each program's source block through a
@@ -90,7 +89,7 @@ def dequant_matmul_slots(x, qt: QTensor, slots, *, interpret=True,
         bm = 128 if M % 128 == 0 else 8
         return dequant_matmul_slots_pallas(
             x, qt.packed, scale, zero, slots, bits=qt.bits,
-            group_size=qt.group_size, bm=bm, interpret=interpret)
+            group_size=qt.group_size, bm=bm)
     gathered = QTensor(qt.packed[slots], scale[slots], zero[slots], None,
                        qt.bits, qt.group_size, (B,) + tuple(qt.shape[1:]))
     w = _dequant_rows(gathered, gathered.scale, gathered.zero)
@@ -98,14 +97,13 @@ def dequant_matmul_slots(x, qt: QTensor, slots, *, interpret=True,
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
-                    interpret=True, use_kernel=True):
+                    use_kernel=True):
     BH, Sq, d = q.shape
     ok = (use_kernel and Sq % 8 == 0 and k.shape[1] % 128 == 0
           and d % 8 == 0)
     if ok:
         bq = 128 if Sq % 128 == 0 else 8
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      bq=bq, q_offset=q_offset,
-                                      interpret=interpret)
+                                      bq=bq, q_offset=q_offset)
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                    q_offset=q_offset)

@@ -43,6 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -153,8 +155,8 @@ def build_page_worklist(pages, n_live, q_lo, q_hi, page_size: int, *,
 
 # ----------------------------------------------------------------------
 # Pallas kernel: the work list IS the grid
-def _ragged_kernel(wrow_ref, wpage_ref, wflags_ref, qpos_ref,
-                   q_ref, kp_ref, vp_ref, ppos_ref, o_ref,
+def _ragged_kernel(wrow_ref, wpage_ref, wflags_ref,
+                   qpos_ref, q_ref, kp_ref, vp_ref, ppos_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale, window, n_groups):
     i = pl.program_id(0)
     first = wflags_ref[i, 0]
@@ -172,13 +174,13 @@ def _ragged_kernel(wrow_ref, wpage_ref, wflags_ref, qpos_ref,
         q = q_ref[0].astype(jnp.float32) * scale      # (C, H, hd)
         k = kp_ref[0].astype(jnp.float32)             # (ps, Hkv, hd)
         v = vp_ref[0].astype(jnp.float32)
-        kpos = ppos_ref[0]                            # (ps,)
+        kpos = ppos_ref[0, 0]                         # (ps,)
         C, H, hd = q.shape
         Hkv = k.shape[1]
         qg = q.reshape(C, Hkv, n_groups, hd)
         s = jnp.einsum("chgd,thd->chgt", qg, k,
                        preferred_element_type=jnp.float32)  # (C,Hkv,G,ps)
-        qp = qpos_ref[wrow_ref[i]]                    # (C,) this row's qpos
+        qp = qpos_ref[0, 0]                           # (C,) this row's qpos
         ok = (kpos[None, :] >= 0) & (kpos[None, :] <= qp[:, None])
         if window is not None:
             ok &= (qp[:, None] - kpos[None, :]) < window
@@ -204,7 +206,7 @@ def _ragged_kernel(wrow_ref, wpage_ref, wflags_ref, qpos_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def ragged_attention_pallas(q, kp, vp, ppos, qpos, wrow, wpage, wflags, *,
-                            window: Optional[int] = None, interpret=True):
+                            window: Optional[int] = None, interpret=None):
     """q: (B, C, H, hd) against paged KV via a (row, page) work list.
 
     The work list arrays ride in as scalar-prefetch arguments; the grid
@@ -220,19 +222,20 @@ def ragged_attention_pallas(q, kp, vp, ppos, qpos, wrow, wpage, wflags, *,
     assert H % Hkv == 0
     n_work = wrow.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=3,
         grid=(n_work,),
         in_specs=[
+            pl.BlockSpec((1, 1, C), lambda i, wr, wp, wf: (wr[i], 0, 0)),
             pl.BlockSpec((1, C, H, hd),
-                         lambda i, wr, wp, wf, qp: (wr[i], 0, 0, 0)),
+                         lambda i, wr, wp, wf: (wr[i], 0, 0, 0)),
             pl.BlockSpec((1, ps, Hkv, hd),
-                         lambda i, wr, wp, wf, qp: (wp[i], 0, 0, 0)),
+                         lambda i, wr, wp, wf: (wp[i], 0, 0, 0)),
             pl.BlockSpec((1, ps, Hkv, hd),
-                         lambda i, wr, wp, wf, qp: (wp[i], 0, 0, 0)),
-            pl.BlockSpec((1, ps), lambda i, wr, wp, wf, qp: (wp[i], 0)),
+                         lambda i, wr, wp, wf: (wp[i], 0, 0, 0)),
+            pl.BlockSpec((1, 1, ps), lambda i, wr, wp, wf: (wp[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, C, H, hd),
-                               lambda i, wr, wp, wf, qp: (wr[i], 0, 0, 0)),
+                               lambda i, wr, wp, wf: (wr[i], 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((C, H), jnp.float32),
             pltpu.VMEM((C, H), jnp.float32),
@@ -244,27 +247,31 @@ def ragged_attention_pallas(q, kp, vp, ppos, qpos, wrow, wpage, wflags, *,
                           window=window, n_groups=H // Hkv),
         out_shape=jax.ShapeDtypeStruct((B, C, H, hd), q.dtype),
         grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        # qpos is a blocked input, not a scalar-prefetch one: Mosaic cannot
+        # read a vector row out of a 2-D SMEM array.  qpos/ppos get a unit
+        # middle axis because Mosaic tiles a block's last two dims: a
+        # (1, n) row block of a 2-D array is refused, (1, 1, n) is whole
     )(wrow.astype(jnp.int32), wpage.astype(jnp.int32),
-      wflags.astype(jnp.int32), qpos.astype(jnp.int32), q, kp, vp, ppos)
+      wflags.astype(jnp.int32), qpos.astype(jnp.int32)[:, None, :], q, kp,
+      vp, ppos[:, None, :])
 
 
 # ----------------------------------------------------------------------
 def ragged_attention(q, kp, vp, ppos, pages, qpos, *,
                      window: Optional[int] = None,
                      q_chunk: Optional[int] = None,
-                     worklist=None, interpret=True, layer=None):
+                     worklist=None, layer=None):
     """Dispatch: with a host-built ``worklist`` (wrow, wpage, wflags)
     run the Pallas page-skip kernel; inside jitted model programs (no
-    host work list) the gather reference runs — on this CPU host that
-    is the production path, and it is bitwise ``attention_core``."""
+    host work list) the gather reference runs — on every backend that is
+    the served path today, and it is bitwise ``attention_core``."""
     if worklist is not None:
         assert layer is None, "worklist kernel takes per-layer pools"
         wrow, wpage, wflags = worklist
         return ragged_attention_pallas(q, kp, vp, ppos, qpos,
                                        jnp.asarray(wrow), jnp.asarray(wpage),
-                                       jnp.asarray(wflags), window=window,
-                                       interpret=interpret)
+                                       jnp.asarray(wflags), window=window)
     return ragged_attention_reference(q, kp, vp, ppos, pages, qpos,
                                       window=window, q_chunk=q_chunk,
                                       layer=layer)

@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax-importing import: jax locks device count on init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax-importing import: jax locks device count and
+# platform on init.  The fake host devices are the point, and a dry run
+# must never take a TPU away from the process that serves on it.
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 on the production meshes with 512 placeholder host devices.
 

@@ -18,11 +18,8 @@ def _mesh(shape, axes):
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devs)} — run under "
             f"dryrun.py (which sets xla_force_host_platform_device_count)")
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5; older jax is
-        # all-Auto by default, which is exactly what we request here
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devs[:n], **kw)
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,10 +35,6 @@ def make_smoke_mesh(n_data: int = 2, n_model: int = 2):
 
 
 def mesh_context(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.set_mesh`` on jax >= 0.6, the legacy ``with mesh:`` global-mesh
-    context on older jax (where ``sharding/specs._current_mesh`` reads it
-    back via ``thread_resources``)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh
+    (``jax.set_mesh``; ``sharding/specs._current_mesh`` reads it back)."""
+    return jax.set_mesh(mesh)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # fake host devices; never the TPU
 """Perf-iteration workbench: compile one (arch x shape x mesh) and break
 the roofline terms down to the responsible HLO ops (with jax op metadata),
 so each hillclimb hypothesis can be checked against the actual schedule.

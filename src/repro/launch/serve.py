@@ -235,10 +235,13 @@ def build_parser() -> argparse.ArgumentParser:
                          "chrome://tracing or ui.perfetto.dev) of every "
                          "request's lifecycle spans and every step's "
                          "phase breakdown")
-    ap.add_argument("--roofline-hw", default="t4",
+    ap.add_argument("--roofline-hw", default=None,
                     choices=["t4", "3060", "3080m", "a100"],
-                    help="cost-model hardware target for the measured-vs-"
-                         "predicted roofline accounting")
+                    help="cost-model GPU preset for the measured-vs-"
+                         "predicted roofline accounting (default: t4 on "
+                         "the CPU; on an accelerator the accounting is "
+                         "off unless a preset is named — no preset "
+                         "describes a TPU)")
     return ap
 
 
@@ -300,6 +303,8 @@ def print_spec_summary(obs):
 
 def main():
     args = build_parser().parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.kv_page is not None and not args.continuous:
         raise SystemExit("--kv-page targets the continuous engine's "
@@ -405,7 +410,8 @@ def main():
         write_outputs(args, eng.obs, {
             "engine": "offload", "arch": cfg.name,
             "offloaded": True, "timing": eng.obs.timing,
-            "plane": eng._exec.plane, "roofline": eng.obs.timing,
+            "plane": eng._exec.plane,
+            "roofline": eng.obs.roofline is not None,
             "speculative": draft_k > 0})
         return
 
@@ -544,7 +550,8 @@ def main():
             "kv_layout": "paged" if args.kv_page is not None else "dense",
             "offloaded": offload_eng is not None,
             "timing": eng.obs.timing, "plane": eng._exec.plane,
-            "roofline": eng.obs.timing, "speculative": draft_k > 0,
+            "roofline": eng.obs.roofline is not None,
+            "speculative": draft_k > 0,
             "prefix_cache": prefix_pages > 0, "kv_host": preempt,
             "faults": True})
         return

@@ -151,7 +151,8 @@ class Telemetry:
     works."""
 
     def __init__(self, *, timing: bool = True, trace: bool = False,
-                 roofline_hw: str = "t4", roofline_window: int = 32,
+                 roofline_hw: Optional[str] = None,
+                 roofline_window: int = 32,
                  clock_ns=time.perf_counter_ns):
         self.registry = MetricsRegistry()
         self.timing = timing
@@ -189,8 +190,20 @@ class Telemetry:
     def attach_roofline(self, cfg, *, expert_bits: int = 16,
                         attn_bits: int = 16, expert_bytes: float = 0.0,
                         h2d_counts_fn=None) -> None:
+        """Attach the measured-vs-predicted accountant.  Its predictions
+        come from ``core.cost_model.HARDWARE``, which holds GPU presets
+        only: with ``roofline_hw=None`` the CPU host models the T4 (a
+        device it has none of), while on an accelerator no preset
+        describes the chip, so nothing is attached and the ``roofline``
+        namespace is absent (not measured) unless a preset was named."""
+        hw = self.roofline_hw
+        if hw is None:
+            import jax
+            if jax.default_backend() != "cpu":
+                return
+            hw = "t4"
         self.roofline = RooflineAccountant(
-            self.registry, cfg, hw=self.roofline_hw,
+            self.registry, cfg, hw=hw,
             window=self.roofline_window, expert_bits=expert_bits,
             attn_bits=attn_bits, expert_bytes=expert_bytes,
             h2d_counts_fn=h2d_counts_fn)

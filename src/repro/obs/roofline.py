@@ -38,7 +38,7 @@ class RooflineAccountant:
     h2d fields stay 0).
     """
 
-    def __init__(self, registry, cfg, *, hw: str = "t4",
+    def __init__(self, registry, cfg, *, hw: str,
                  window: int = 32, expert_bits: int = 16,
                  attn_bits: int = 16, expert_bytes: float = 0.0,
                  h2d_counts_fn: Optional[Callable[[], Tuple[int, int, int,

@@ -30,19 +30,10 @@ from jax.sharding import PartitionSpec as P
 
 
 def _current_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:
-        m = None
-    if m is None or not getattr(m, "axis_names", ()):
-        # jax < 0.5: the ambient mesh is the legacy global-mesh context
-        # entered via ``with mesh:`` (launch/mesh.mesh_context)
-        try:
-            from jax.interpreters import pxla
-            m = pxla.thread_resources.env.physical_mesh
-        except Exception:
-            return None
-    if m is None or not getattr(m, "axis_names", ()):
+    """The ambient mesh installed by ``launch/mesh.mesh_context``, or
+    None outside one (CPU unit tests)."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or not m.axis_names:
         return None
     return m
 

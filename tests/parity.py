@@ -23,11 +23,15 @@ Pieces:
 * :func:`assert_tokens_equal` / :func:`offload_counters` /
   :func:`continuous_counters` — the equality assertions, with readable
   divergence output.
+* :func:`assert_close_f32` / :func:`assert_states_close_f32` — logits and
+  decode state of two float32 programs that compute the same values with
+  differently shaped or ordered reductions (:data:`F32_REASSOC_TOL`).
 """
 from __future__ import annotations
 
 from typing import Dict, List, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.offload_engine import OffloadEngine, generate_plain
@@ -95,6 +99,41 @@ def oracle_streams(params, cfg, prompts, max_news,
 def assert_tokens_equal(got, want, label: str) -> None:
     assert got == want, (f"{label}: token stream diverged\n"
                          f"  got : {got}\n  want: {want}")
+
+
+# Two float32 programs computing the same values with differently shaped
+# reductions — a prefill chunk changes a matmul's row count, so XLA tiles
+# and sums it in another order; a fused batched dequant-matmul contracts
+# in another order than a gather einsum — differ by float32
+# reassociation: ~sqrt(K) * 2^-24 of the output scale per layer, ~1e-6
+# over these shallow stacks (measured: at most 3e-6 of it).  A wrong
+# mask, position, recurrent carry or expert moves the output by its own
+# scale.  Equal bits are promised only by identical programs on
+# identical shapes, and no accelerator keeps even that.
+F32_REASSOC_TOL = 1e-5
+
+
+def assert_close_f32(got, want, label: str,
+                     tol: float = F32_REASSOC_TOL) -> None:
+    """Float arrays: ``max |got - want| <= tol * max |want|``.  Integer
+    arrays (positions, page tables) must match exactly."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape, (label, got.shape, want.shape)
+    if not np.issubdtype(want.dtype, np.floating):
+        np.testing.assert_array_equal(got, want, err_msg=label)
+        return
+    scale = max(float(np.abs(want).max()), 1e-30)
+    err = float(np.abs(got.astype(np.float64) - want).max())
+    assert err <= tol * scale, (f"{label}: max abs err {err} over "
+                                f"{tol} x max |ref| {scale}")
+
+
+def assert_states_close_f32(got, want, label: str) -> None:
+    """:func:`assert_close_f32` on every leaf of two decode states."""
+    la, lb = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(la) == len(lb), label
+    for i, (a, b) in enumerate(zip(la, lb)):
+        assert_close_f32(a, b, f"{label} (state leaf {i})")
 
 
 # ----------------------------------------------------------------------

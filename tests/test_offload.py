@@ -243,9 +243,11 @@ def _packed_moe_setup(bits=3):
 @pytest.mark.parametrize("fused", [True, False])
 def test_moe_packed_matches_gather_on_dequantized_stack(fused):
     """moe_apply_packed == moe_apply_gather over the dequantized expert
-    stack, bitwise — per-slot dequant commutes with stacking and both
-    paths run the same matmuls (the packed-execution parity invariant
-    at the single-layer level)."""
+    stack — per-slot dequant commutes with stacking (the packed-execution
+    parity invariant at the single-layer level).  ``fused=False`` runs
+    the gather path's very einsums, so it stays bitwise; the fused
+    batched dequant-matmul contracts in its own order and matches to
+    float32 reassociation."""
     import jax.numpy as jnp
 
     from repro.core.trace import stacked_routers
@@ -265,7 +267,10 @@ def test_moe_packed_matches_gather_on_dequantized_stack(fused):
         p_moe, cfg, x, store, pstate, jnp.asarray(l), routers,
         lookahead=spec.lookahead, n_spec=spec.num_speculative, fused=fused)
     assert (np.asarray(route["ids"]) == np.asarray(route_ref["ids"])).all()
-    assert (np.asarray(y) == np.asarray(y_ref)).all()
+    if fused:
+        parity.assert_close_f32(y, y_ref, "fused packed vs gather")
+    else:
+        assert (np.asarray(y) == np.asarray(y_ref)).all()
     # cold pool -> both routed experts were demand loads
     counts = np.asarray(pstate2.counts)
     assert counts[2] == cfg.moe.top_k

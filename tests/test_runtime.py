@@ -1,5 +1,5 @@
-"""Unified step-plan runtime (DESIGN.md §8): chunked prefill is
-bitwise-identical to whole-prompt prefill on every plane, the
+"""Unified step-plan runtime (DESIGN.md §8): chunked prefill matches
+whole-prompt prefill on every plane (to float32 reassociation), the
 token-budget policy's scheduling invariants hold on random traces
 (property-based when ``hypothesis`` is installed, with a seeded stdlib
 fallback that ALWAYS runs), engines share one compiled block program per
@@ -28,15 +28,6 @@ from repro.serving.engine import ContinuousEngine
 import parity
 
 
-def _state_leaves(state):
-    return [np.asarray(l) for l in jax.tree.leaves(state)]
-
-
-def _assert_states_bitwise(a, b):
-    for la, lb in zip(_state_leaves(a), _state_leaves(b)):
-        np.testing.assert_array_equal(la, lb)
-
-
 def _prompt(cfg, S, seed=0, B=1):
     rng = np.random.default_rng(seed)
     return rng.integers(1, cfg.vocab_size, (B, S)).astype(np.int32)
@@ -45,17 +36,18 @@ def _prompt(cfg, S, seed=0, B=1):
 # ----------------------------------------------------------------------
 # chunked == whole, bitwise, per plane
 def test_chunked_prefill_bitwise_plain(tiny_moe_cfg, tiny_moe_params):
-    """Acceptance: chunk size never changes a bit of the prefill result
-    — logits, KV state and positions — because a chunk only changes the
-    number of query rows per dispatch, never a reduction shape."""
+    """Acceptance: chunk size never changes the prefill result — logits,
+    KV state and positions — beyond float32 reassociation against the
+    whole-prompt float32 run: a chunk only changes the number of query
+    rows per dispatch (positions and page bookkeeping stay exact)."""
     ex = Executor(tiny_moe_params, tiny_moe_cfg)
     prompt = _prompt(tiny_moe_cfg, 13, seed=3, B=2)  # B=2 lock-step rows
     whole_l, whole_s, _ = ex.prefill(prompt, 32)
     for chunk in (1, 4, 5, 13, 64):
         l, s, _ = ex.prefill(prompt, 32, chunk=chunk)
-        np.testing.assert_array_equal(np.asarray(whole_l[:, -1]),
-                                      np.asarray(l[:, -1]))
-        _assert_states_bitwise(whole_s, s)
+        parity.assert_close_f32(l[:, -1], whole_l[:, -1],
+                                f"chunk={chunk} logits")
+        parity.assert_states_close_f32(s, whole_s, f"chunk={chunk}")
 
 
 @pytest.fixture(scope="module")
@@ -71,45 +63,44 @@ def packed_setup(tiny_moe_cfg, tiny_moe_params):
 @pytest.mark.parametrize("plane", ["packed_vectorized", "packed_pipelined"])
 def test_chunked_prefill_bitwise_packed(tiny_moe_cfg, packed_setup, plane):
     """Same acceptance on the packed planes — chunks stream experts from
-    the host store, and the result equals BOTH any other chunking AND
-    the plain-plane prefill of the dequantized model, bitwise."""
+    the host store, and the result matches BOTH any other chunking AND
+    the float32 plain-plane prefill of the dequantized model, to float32
+    reassociation (the fused dequant-matmul contracts in its own
+    order)."""
     spec, exec_params, store, qdeq = packed_setup
     ex = Executor(exec_params, tiny_moe_cfg, plane=plane, spec=spec,
                   store=store)
     prompt = _prompt(tiny_moe_cfg, 11, seed=5)
     whole_l, whole_s, _ = ex.prefill(prompt, 24)
+    # packed prefill == dequantized-model prefill (plain plane)
+    oracle = Executor(qdeq, tiny_moe_cfg)
+    ol, os_, _ = oracle.prefill(prompt, 24)
+    parity.assert_close_f32(whole_l[:, -1], ol[:, -1], "packed vs oracle")
+    parity.assert_states_close_f32(whole_s, os_, "packed vs oracle")
     for chunk in (3, 11):
         l, s, _ = ex.prefill(prompt, 24, chunk=chunk)
-        np.testing.assert_array_equal(np.asarray(whole_l[:, -1]),
-                                      np.asarray(l[:, -1]))
-        _assert_states_bitwise(whole_s, s)
-    # packed chunked prefill == dequantized-model prefill (plain plane)
-    oracle = Executor(qdeq, tiny_moe_cfg)
-    ol, os_, _ = oracle.prefill(prompt, 24, chunk=4)
-    np.testing.assert_array_equal(np.asarray(whole_l[:, -1]),
-                                  np.asarray(ol[:, -1]))
-    _assert_states_bitwise(whole_s, os_)
+        parity.assert_close_f32(l[:, -1], ol[:, -1],
+                                f"packed chunk={chunk} logits")
+        parity.assert_states_close_f32(s, os_, f"packed chunk={chunk}")
 
 
 def test_recurrent_stacks_prefill_chunked_bitwise():
     """Recurrent mixers run the SAME chunked prefill program as
     attention stacks (DESIGN.md §12): the chunk forms compose their
     carries exactly, so whole-prompt prefill and any chunking of it
-    agree bitwise on every state plane — the chunkwise==recurrent
-    guarantee of tests/test_recurrent.py lifted to the executor.
-    (Chunk sizes avoid a size-1 tail: the dense MLP's S=1 GEMV path
-    folds differently from its GEMM path at ~1e-7, so only C >= 2
-    chunkings of MLP-bearing stacks are bitwise.)"""
+    agree on every state plane to float32 reassociation (the chunk's
+    matmuls change row count) — the chunkwise==recurrent guarantee of
+    tests/test_recurrent.py lifted to the executor."""
     cfg = get_config("recurrentgemma-9b").reduced()
     params = T.init_model(jax.random.key(2), cfg)
     prompt = _prompt(cfg, 7, seed=2)
     ex = Executor(params, cfg)
     whole_l, whole_s, _ = ex.prefill(prompt, 16)
-    for chunk in (4, 7):  # 7 -> 4+3 and whole; no size-1 tails
+    for chunk in (4, 7):  # 7 -> 4+3 and whole
         l, s, _ = ex.prefill(prompt, 16, chunk=chunk)
-        np.testing.assert_array_equal(np.asarray(whole_l[:, -1]),
-                                      np.asarray(l[:, -1]))
-        _assert_states_bitwise(whole_s, s)
+        parity.assert_close_f32(l[:, -1], whole_l[:, -1],
+                                f"chunk={chunk} logits")
+        parity.assert_states_close_f32(s, whole_s, f"chunk={chunk}")
     # ... and generate_plain is prefill-chunk invariant at token level
     out = generate_plain(params, cfg, prompt, 5)
     assert out.shape == (1, 5)
