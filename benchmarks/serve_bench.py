@@ -310,7 +310,7 @@ def run_paged_kv(params, cfg, *, smoke=False, max_slots=4, seed=0,
 # workload 4: telemetry overhead (DESIGN.md §10) — the hot-path contract
 def run_telemetry_overhead(params, cfg, *, smoke=False, max_slots=4,
                            seed=0):
-    """Telemetry OFF vs fully ON (timing + tracing + roofline) over the
+    """Telemetry OFF vs fully ON (timing + tracing) over the
     same mixed workload.  Asserts the §10 contract: bitwise-identical
     tokens, and with full tracing the decode throughput stays within 5%
     of the uninstrumented engine.  Best-of-N alternating passes (the

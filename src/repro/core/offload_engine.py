@@ -36,7 +36,6 @@ the eviction sequence — in
 from __future__ import annotations
 
 import dataclasses
-import time
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
@@ -306,9 +305,8 @@ class OffloadEngine:
         self.usage = ExpertUsageTracker(self.n_moe_layers,
                                         cfg.moe.num_experts)
         # telemetry plane (DESIGN.md §10): cumulative transfer accounting
-        # feeds the offload collector; each generate() closes one
-        # roofline window from the stats it already computed (zero extra
-        # device fetches) and traces its prefill/decode spans
+        # feeds the offload collector; each generate() traces its
+        # prefill/decode spans
         from repro.obs import Telemetry, jit_cache_metrics
         self.obs = telemetry if telemetry is not None else Telemetry.off()
         self.last_stats: Optional[OffloadStats] = None
@@ -326,11 +324,6 @@ class OffloadEngine:
         if self.obs.timing:
             self.obs.declare_request_schema()
             self._exec.set_observer(self.obs.exec_observer(self._exec.plane))
-            self.obs.attach_roofline(
-                cfg,
-                expert_bits=self.spec.expert_bits if quantized else 16,
-                attn_bits=self.spec.attn_bits if quantized else 16,
-                expert_bytes=self.expert_bytes)
 
     # ------------------------------------------------------------------
     def _ensure_spec_metrics(self):
@@ -350,8 +343,7 @@ class OffloadEngine:
                 "bytes_h2d": c.bytes_h2d,
                 "bytes_per_token": c.bytes_h2d / max(1, c.n_tokens)}
 
-    def _record_generate(self, stats: OffloadStats, prompt_len: int,
-                         decode_s: float) -> None:
+    def _record_generate(self, stats: OffloadStats) -> None:
         """Fold one generate()'s measured stats into the telemetry plane."""
         self.last_stats = stats
         c = self._cum
@@ -360,13 +352,6 @@ class OffloadEngine:
         c.spec_hits += stats.spec_hits
         c.demand_loads += stats.demand_loads
         c.spec_loads += stats.spec_loads
-        if self.obs.roofline is not None and decode_s > 0:
-            self.obs.roofline.add_window(
-                stats.n_tokens, decode_s,
-                demand_loads=stats.demand_loads,
-                spec_loads=stats.spec_loads,
-                hits=stats.hits, spec_hits=stats.spec_hits,
-                context_len=prompt_len + stats.n_tokens / 2.0)
 
     def metrics(self):
         """Namespaced telemetry snapshot (``repro.obs.schema``)."""
@@ -434,7 +419,6 @@ class OffloadEngine:
         rng, tok = self._next_token(rng, pre_logits, sampler)
         out = [int(tok[0, 0])]
         obs.req_decode_start(rid)
-        t0 = time.perf_counter() if obs.timing else 0.0
         for step_i in range(max_new_tokens - 1):
             logits, state, _, (info_stack, _) = self._exec.decode(
                 state, tok, collect_info=True)
@@ -442,13 +426,12 @@ class OffloadEngine:
             stats.n_tokens += 1
             rng, tok = self._next_token(rng, logits, sampler)
             out.append(int(tok[0, 0]))
-        decode_s = time.perf_counter() - t0 if obs.timing else 0.0
         for c in caches:
             stats.hits += c.hits
             stats.spec_hits += c.spec_hits
             stats.demand_loads += c.demand
             stats.spec_loads += c.spec_loads
-        self._record_generate(stats, int(prompt.shape[1]), decode_s)
+        self._record_generate(stats)
         obs.req_finished(rid, len(out), "length")
         return np.asarray(out)[None], stats
 
@@ -488,13 +471,11 @@ class OffloadEngine:
         rng, tok = self._next_token(rng, pre_logits, sampler)
         out = [int(tok[0, 0])]
         obs.req_decode_start(rid)
-        t0 = time.perf_counter() if obs.timing else 0.0
         for _ in range(max_new_tokens - 1):
             logits, state, pstate, route_ids = dec.decode(state, tok, pstate)
             self.usage.update([np.asarray(i) for i in route_ids])
             rng, tok = self._next_token(rng, logits, sampler)
             out.append(int(tok[0, 0]))
-        decode_s = time.perf_counter() - t0 if obs.timing else 0.0
         counts = np.asarray(pstate.counts)
         stats = OffloadStats(
             n_tokens=max_new_tokens - 1,
@@ -502,7 +483,7 @@ class OffloadEngine:
             demand_loads=int(counts[2]), spec_loads=int(counts[3]),
             expert_bytes=self.expert_bytes)
         self._last_pool_state = pstate  # inspectable by tests/examples
-        self._record_generate(stats, int(prompt.shape[1]), decode_s)
+        self._record_generate(stats)
         obs.req_finished(rid, len(out), "length")
         return np.asarray(out)[None], stats
 
@@ -563,7 +544,6 @@ class OffloadEngine:
         draft.start(np.asarray(prompt), max_len + k)
         prompt_list = [int(t) for t in np.asarray(prompt).reshape(-1)]
         obs.req_decode_start(rid)
-        t0 = time.perf_counter() if obs.timing else 0.0
 
         def _one_step(tok):
             nonlocal state, pstate
@@ -600,7 +580,6 @@ class OffloadEngine:
             draft.accept(a)
             spec_m.round(k_eff, a)
 
-        decode_s = time.perf_counter() - t0 if obs.timing else 0.0
         if packed:
             counts = np.asarray(pstate.counts)
             stats.hits = int(counts[0])
@@ -615,7 +594,7 @@ class OffloadEngine:
                 stats.demand_loads += c.demand
                 stats.spec_loads += c.spec_loads
         spec_m.add_bytes(stats.bytes_h2d)
-        self._record_generate(stats, S, decode_s)
+        self._record_generate(stats)
         obs.req_finished(rid, len(out), "length")
         return np.asarray(out)[None], stats
 

@@ -228,20 +228,12 @@ def build_parser() -> argparse.ArgumentParser:
                     help="write the full namespaced metrics snapshot "
                          "(schema: repro.obs.schema, validated by "
                          "tools/check_metrics_schema.py) and enable "
-                         "step/request timing + roofline accounting "
-                         "(DESIGN.md §10)")
+                         "step/request timing (DESIGN.md §10)")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a Chrome trace_event JSON (load in "
                          "chrome://tracing or ui.perfetto.dev) of every "
                          "request's lifecycle spans and every step's "
                          "phase breakdown")
-    ap.add_argument("--roofline-hw", default=None,
-                    choices=["t4", "3060", "3080m", "a100"],
-                    help="cost-model GPU preset for the measured-vs-"
-                         "predicted roofline accounting (default: t4 on "
-                         "the CPU; on an accelerator the accounting is "
-                         "off unless a preset is named — no preset "
-                         "describes a TPU)")
     return ap
 
 
@@ -251,8 +243,7 @@ def make_telemetry(args):
     from repro.obs import Telemetry
     if args.metrics_json is None and args.trace is None:
         return Telemetry.off()
-    return Telemetry(timing=True, trace=args.trace is not None,
-                     roofline_hw=args.roofline_hw)
+    return Telemetry(timing=True, trace=args.trace is not None)
 
 
 def write_outputs(args, obs, mode):
@@ -280,15 +271,6 @@ def print_telemetry_summary(obs):
         print(f"[obs] step wall p50={wall['p50']:.2f}ms "
               f"p95={wall['p95']:.2f}ms over {step['timed']} timed steps; "
               f"phases: {shares}")
-    roof = snap.get("roofline")
-    if roof and roof["windows"]:
-        print(f"[obs] roofline({roof['hw']}): measured "
-              f"{roof['measured_tok_s']:.2f} tok/s vs predicted "
-              f"{roof['predicted_tok_s']:.2f} tok/s "
-              f"(delta x{roof['delta_ratio']:.2f}); h2d "
-              f"{roof['measured_h2d_bytes_per_token']/1e6:.2f}MB/tok vs "
-              f"naive {roof['naive_h2d_bytes_per_token']/1e6:.2f}MB/tok "
-              f"(saves x{roof['h2d_savings_ratio']:.1f})")
 
 
 def print_spec_summary(obs):
@@ -411,7 +393,6 @@ def main():
             "engine": "offload", "arch": cfg.name,
             "offloaded": True, "timing": eng.obs.timing,
             "plane": eng._exec.plane,
-            "roofline": eng.obs.roofline is not None,
             "speculative": draft_k > 0})
         return
 
@@ -550,7 +531,6 @@ def main():
             "kv_layout": "paged" if args.kv_page is not None else "dense",
             "offloaded": offload_eng is not None,
             "timing": eng.obs.timing, "plane": eng._exec.plane,
-            "roofline": eng.obs.roofline is not None,
             "speculative": draft_k > 0,
             "prefix_cache": prefix_pages > 0, "kv_host": preempt,
             "faults": True})

@@ -8,11 +8,12 @@ trace, so no two outputs can ever disagree.
   ``Telemetry.off()`` serves the pull-time collectors that back the
   legacy ``stats()`` shim);
 * an optional :class:`~repro.obs.tracing.Tracer` (``trace=True``)
-  recording per-request lifecycle spans and per-step phase spans as
-  Chrome ``trace_event`` JSON;
-* an optional :class:`~repro.obs.roofline.RooflineAccountant`
-  (``timing=True``) comparing measured tokens/s and h2d bytes against
-  ``core.cost_model`` predictions per step window.
+  recording per-request lifecycle spans, per-step phase spans and the
+  executor's dispatch-site spans as Chrome ``trace_event`` JSON;
+* :class:`Span` (``timing=True``): one host span written to both sinks
+  where the work begins and ends — a Chrome ``X`` event in the tracer
+  and a ``jax.profiler.TraceAnnotation``, which lands in a running
+  profile's ``.xplane.pb`` on the device trace's clock.
 
 Hot-path contract (tested: ``tests/test_obs.py``, asserted in CI by the
 serve_bench ``telemetry_overhead`` scenario): telemetry is host-side
@@ -23,19 +24,21 @@ costs <5% decode tokens/s on the mixed serving workload.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax
 
 from repro.obs.registry import (Counter, Gauge, Histogram, MetricsRegistry,
                                 flatten_legacy, metrics_document,
                                 write_metrics_json)
-from repro.obs.roofline import RooflineAccountant
 from repro.obs.schema import EXEC_KEYS_BY_PLANE, SCHEMA_VERSION
 from repro.obs import tracing
 from repro.obs.tracing import Tracer
 
-__all__ = ["Telemetry", "MetricsRegistry", "Tracer", "RooflineAccountant",
-           "Counter", "Gauge", "Histogram", "ExecPhases", "StepTimer",
+__all__ = ["Telemetry", "MetricsRegistry", "Tracer", "Span", "ExecSpans",
+           "Counter", "Gauge", "Histogram", "StepTimer",
            "SpecMetrics", "flatten_legacy", "metrics_document",
            "write_metrics_json", "jit_cache_metrics", "SCHEMA_VERSION"]
 
@@ -52,50 +55,121 @@ def jit_cache_metrics() -> Dict[str, int]:
             "entries": s["entries"]}
 
 
-class ExecPhases:
-    """Executor dispatch-phase accumulator (``exec`` namespace): the
-    executor calls :meth:`begin` once per step and :meth:`mark` after
-    each dispatch segment; each mark adds the elapsed interval to that
-    phase's counter.  Phase names are plane-specific
-    (``schema.EXEC_KEYS_BY_PLANE``) so the packed pipeline's separate
-    staging dispatch is measurable."""
+class Span:
+    """One host span, begun and ended where the work is: on exit it
+    writes a Chrome ``X`` event (``name``, pid 0, ``tid``, ``args``) into
+    the tracer when tracing, and for its whole extent it holds a
+    ``jax.profiler.TraceAnnotation`` named ``label`` (default ``name``),
+    which a running profile records on the device trace's clock.
+    ``t0``/``t1`` are the telemetry clock's readings at entry and exit.
+    Made only by an enabled :class:`Telemetry` (:meth:`Telemetry.span`)."""
 
-    __slots__ = ("_counters", "_t", "_clock")
+    __slots__ = ("_tel", "name", "tid", "args", "label", "t0", "t1", "_ann")
 
-    def __init__(self, registry: MetricsRegistry, plane: str,
-                 clock_ns=time.perf_counter_ns):
-        self._clock = clock_ns
-        self._counters = {key[:-len("_ns")]: registry.counter("exec", key)
-                          for key in EXEC_KEYS_BY_PLANE[plane]}
-        self._t = 0
+    def __init__(self, tel: "Telemetry", name: str, tid: int,
+                 args: Optional[Dict[str, Any]] = None,
+                 label: Optional[str] = None):
+        self._tel = tel
+        self.name = name
+        self.tid = tid
+        self.args = args
+        self.label = name if label is None else label
+        self.t0 = self.t1 = 0
 
-    def begin(self) -> None:
-        self._t = self._clock()
+    def __enter__(self) -> "Span":
+        self._ann = jax.profiler.TraceAnnotation(self.label)
+        self._ann.__enter__()
+        self.t0 = self._tel.clock_ns()
+        return self
 
-    def mark(self, phase: str) -> None:
-        now = self._clock()
-        self._counters[phase].add(now - self._t)
-        self._t = now
+    def __exit__(self, *exc) -> None:
+        self.t1 = self._tel.clock_ns()
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        tr = self._tel.tracer
+        if tr is not None:
+            tr.complete(self.name, tracing.PID_ENGINE, self.tid,
+                        (self.t0 - tr._t0) / 1e3, (self.t1 - self.t0) / 1e3,
+                        args=self.args)
+
+
+_NO_SPAN = contextlib.nullcontext()  # Telemetry.span with timing off
+
+
+class ExecSpans:
+    """Spans at the executor's dispatch sites (``exec`` namespace, the
+    tracer's ``TID_EXEC`` track).  The executor brackets each site with
+    :meth:`begin` / :meth:`end`; sites never nest.  A span named
+    ``<track>.<site>`` (``exec.mixer``, ``exec.state``,
+    ``engine.route_fetch``) adds its host ns to the ``<site>_ns``
+    counter; ``end`` also counts the jitted programs the site called
+    (``programs``) and the blocking device→host fetches it made
+    (``fetches``).  Site names per plane: ``schema.EXEC_KEYS_BY_PLANE``."""
+
+    __slots__ = ("_tel", "_ns", "programs", "fetches", "_open")
+
+    def __init__(self, tel: "Telemetry", plane: str):
+        self._tel = tel
+        reg = tel.registry
+        keys = EXEC_KEYS_BY_PLANE[plane]
+        self._ns = {key[:-len("_ns")]: reg.counter("exec", key)
+                    for key in keys if key.endswith("_ns")}
+        self.programs = reg.counter("exec", "programs")
+        self.fetches = (reg.counter("exec", "fetches")
+                        if "fetches" in keys else None)
+        self._open: Optional[Span] = None
+
+    def begin(self, name: str, layer: Optional[int] = None) -> None:
+        sp = Span(self._tel, name, tracing.TID_EXEC,
+                  None if layer is None else {"layer": layer})
+        sp.__enter__()
+        self._open = sp
+
+    def end(self, programs: int = 0, fetches: int = 0) -> None:
+        sp = self._open
+        self._open = None
+        sp.__exit__()
+        self._ns[sp.name.split(".", 1)[1]].add(sp.t1 - sp.t0)
+        if programs:
+            self.programs.add(programs)
+        if fetches:
+            self.fetches.add(fetches)
 
 
 class StepTimer:
-    """One engine step's phase breakdown; collected by
-    :meth:`Telemetry.step_end` into the ``step`` counters/histogram and
-    (when tracing) into nested ``step``/phase spans."""
+    """One engine step's phases (``_STEP_PHASES``) as sibling spans on
+    the tracer's ``TID_STEPS`` track, each annotated ``engine.<phase>``
+    for the profiler: :meth:`phase` ends the open phase and opens the
+    next where it starts (re-entering the open phase is a no-op).
+    :meth:`Telemetry.step_end` closes the last one and folds the step
+    into the ``step`` counters/histogram and its ``step <i>`` span."""
 
-    __slots__ = ("t0", "marks", "_t", "_clock", "index")
+    __slots__ = ("_tel", "index", "phases", "_open", "_exec0")
 
-    def __init__(self, index: int, clock_ns):
-        self._clock = clock_ns
+    def __init__(self, tel: "Telemetry", index: int):
+        self._tel = tel
         self.index = index
-        self.t0 = clock_ns()
-        self._t = self.t0
-        self.marks: List[Tuple[str, int, int]] = []  # (phase, t_start, t_end)
+        self.phases: List[Tuple[str, int, int]] = []  # (phase, t0, t1)
+        self._open: Optional[Span] = None
+        self._exec0 = tel._exec_tally()
 
-    def mark(self, phase: str) -> None:
-        now = self._clock()
-        self.marks.append((phase, self._t, now))
-        self._t = now
+    def phase(self, name: str) -> None:
+        sp = self._open
+        if sp is not None:
+            if sp.name == name:
+                return
+            self.close()
+        sp = Span(self._tel, name, tracing.TID_STEPS,
+                  label="engine." + name)
+        sp.__enter__()
+        self._open = sp
+
+    def close(self) -> None:
+        sp = self._open
+        if sp is not None:
+            self._open = None
+            sp.__exit__()
+            self.phases.append((sp.name, sp.t0, sp.t1))
 
 
 class SpecMetrics:
@@ -145,22 +219,20 @@ class SpecMetrics:
 
 class Telemetry:
     """The facade: ``timing`` enables per-step/per-request measurement
-    (+ roofline), ``trace`` additionally records Chrome trace spans.
-    ``Telemetry.off()`` keeps only the pull-time registry — the zero-
-    cost mode every engine owns by default so ``stats()`` always
-    works."""
+    and the spans' profiler annotations, ``trace`` additionally records
+    Chrome trace spans.  ``Telemetry.off()`` keeps only the pull-time
+    registry — the zero-cost mode every engine owns by default so
+    ``stats()`` always works: no span, clock reading or annotation is
+    made (the engine's and executor's ``is not None`` checks on their
+    step timer and :class:`ExecSpans` skip every site)."""
 
     def __init__(self, *, timing: bool = True, trace: bool = False,
-                 roofline_hw: Optional[str] = None,
-                 roofline_window: int = 32,
                  clock_ns=time.perf_counter_ns):
         self.registry = MetricsRegistry()
         self.timing = timing
         self.clock_ns = clock_ns
         self.tracer: Optional[Tracer] = Tracer(clock_ns) if trace else None
-        self.roofline: Optional[RooflineAccountant] = None
-        self.roofline_hw = roofline_hw
-        self.roofline_window = roofline_window
+        self._exec: Optional[ExecSpans] = None
         self._step: Dict[str, Any] = {}
         self._req: Dict[str, Any] = {}
         self._req_ts: Dict[int, Dict[str, float]] = {}
@@ -187,63 +259,59 @@ class Telemetry:
                                                      "queue_wait_steps"),
                      "gen_tokens": r.histogram("request", "gen_tokens")}
 
-    def attach_roofline(self, cfg, *, expert_bits: int = 16,
-                        attn_bits: int = 16, expert_bytes: float = 0.0,
-                        h2d_counts_fn=None) -> None:
-        """Attach the measured-vs-predicted accountant.  Its predictions
-        come from ``core.cost_model.HARDWARE``, which holds GPU presets
-        only: with ``roofline_hw=None`` the CPU host models the T4 (a
-        device it has none of), while on an accelerator no preset
-        describes the chip, so nothing is attached and the ``roofline``
-        namespace is absent (not measured) unless a preset was named."""
-        hw = self.roofline_hw
-        if hw is None:
-            import jax
-            if jax.default_backend() != "cpu":
-                return
-            hw = "t4"
-        self.roofline = RooflineAccountant(
-            self.registry, cfg, hw=hw,
-            window=self.roofline_window, expert_bits=expert_bits,
-            attn_bits=attn_bits, expert_bytes=expert_bytes,
-            h2d_counts_fn=h2d_counts_fn)
+    def span(self, name: str, track: int = tracing.TID_EXEC, *,
+             label: Optional[str] = None, **args):
+        """A :class:`Span` on the engine process's ``track`` (a no-op
+        context with timing off).  Hot paths do not call this per site
+        with timing off: they hold a :class:`StepTimer` /
+        :class:`ExecSpans` that is ``None`` then."""
+        if not self.timing:
+            return _NO_SPAN
+        return Span(self, name, track, args or None, label)
 
-    def exec_observer(self, plane: str) -> Optional[ExecPhases]:
+    def exec_observer(self, plane: str) -> Optional[ExecSpans]:
         if not self.timing:
             return None
-        return ExecPhases(self.registry, plane, self.clock_ns)
+        self._exec = ExecSpans(self, plane)
+        return self._exec
+
+    def _exec_tally(self) -> Tuple[int, int]:
+        """(programs, fetches) the executor sites have counted so far."""
+        xo = self._exec
+        if xo is None:
+            return 0, 0
+        return (xo.programs.value,
+                0 if xo.fetches is None else xo.fetches.value)
 
     # ------------------------------------------------------------------
     # per-step phases
     def step_begin(self, index: int) -> Optional[StepTimer]:
         if not self.timing:
             return None
-        return StepTimer(index, self.clock_ns)
+        return StepTimer(self, index)
 
     def step_end(self, st: Optional[StepTimer], *, n_decode: int = 0,
-                 n_chunks: int = 0, context_len: float = 0.0) -> None:
+                 n_chunks: int = 0) -> None:
         if st is None:
             return
-        end = st.marks[-1][2] if st.marks else st._t
-        wall_ns = end - st.t0
+        st.close()
+        if not st.phases:
+            return
+        t0, end = st.phases[0][1], st.phases[-1][2]
+        wall_ns = end - t0
         self._step["timed"].add(1)
         self._step["wall_ms"].observe(wall_ns / 1e6)
-        for phase, t_lo, t_hi in st.marks:
+        for phase, t_lo, t_hi in st.phases:
             self._step[phase].add(t_hi - t_lo)
         if self.tracer is not None:
+            programs, fetches = self._exec_tally()
             tr = self.tracer
-            base = tr._t0
             tr.complete(f"step {st.index}", tracing.PID_ENGINE,
-                        tracing.TID_STEPS, (st.t0 - base) / 1e3,
+                        tracing.TID_STEPS, (t0 - tr._t0) / 1e3,
                         wall_ns / 1e3,
-                        args={"decode_rows": n_decode, "chunks": n_chunks})
-            for phase, t_lo, t_hi in st.marks:
-                if t_hi > t_lo:
-                    tr.complete(phase, tracing.PID_ENGINE,
-                                tracing.TID_STEPS, (t_lo - base) / 1e3,
-                                (t_hi - t_lo) / 1e3)
-        if self.roofline is not None and n_decode:
-            self.roofline.step(n_decode, wall_ns, context_len)
+                        args={"decode_rows": n_decode, "chunks": n_chunks,
+                              "programs": programs - st._exec0[0],
+                              "fetches": fetches - st._exec0[1]})
 
     # ------------------------------------------------------------------
     # request lifecycle
@@ -311,8 +379,6 @@ class Telemetry:
     # ------------------------------------------------------------------
     # outputs — all three views read the SAME registry
     def snapshot(self) -> Dict[str, Dict[str, Any]]:
-        if self.roofline is not None:
-            self.roofline.flush()  # short runs still report a window
         return self.registry.snapshot()
 
     def legacy_flat(self) -> Dict[str, Any]:

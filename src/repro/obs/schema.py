@@ -17,11 +17,12 @@ Metric names are ``namespace.key``.  Namespaces:
   (``transformer.cached_jit_stats``, minus the unserializable keys).
 * ``step``     — per-engine-step phase breakdown (declared counters +
   wall-clock histogram; only when timing is enabled).
-* ``exec``     — executor dispatch phases, per plane (declared when an
-  observer is attached; the packed planes split mixer/MoE/staging).
+* ``exec``     — executor dispatch sites, per plane (declared when an
+  observer is attached): host ns inside each site's span (the packed
+  planes split embed / mixer / MoE / staging / head, the per-layer state
+  slice and write-back, and the engine's route-id fetch), the count of
+  jitted programs called and of blocking device→host fetches.
 * ``request``  — per-request lifecycle aggregates (declared with timing).
-* ``roofline`` — measured-vs-predicted accounting (gauges; set when a
-  roofline accountant is attached).
 * ``spec``     — token-level draft-and-verify accounting (declared only
   when speculation is wired: per-round proposed/accepted histograms,
   cumulative acceptance rate, h2d bytes per accepted token —
@@ -80,31 +81,25 @@ STEP_KEYS = frozenset({
     "sample_ns", "host_ns", "wall_ms",
 })
 
-# executor dispatch phases differ by plane — the packed_pipelined plane
-# is the only one with a separate speculative-staging dispatch
+# executor dispatch sites differ by plane: ``<site>_ns`` is the host time
+# inside the site's span (``exec.<site>``; ``engine.route_fetch`` for
+# route_fetch_ns), ``programs`` the jitted calls, ``fetches`` the
+# blocking device->host fetches.  The plain plane runs one program a
+# step; the packed planes run per-layer programs (the pipelined plane
+# splits each MoE block into mixer / MoE / speculative staging) around
+# the eager per-layer state slice and write-back, and prefill chunks run
+# mixer + chunk_moe on either packed plane
+_PACKED_EXEC_KEYS = frozenset({"embed_ns", "mixer_ns", "chunk_moe_ns",
+                               "head_ns", "state_ns", "route_fetch_ns",
+                               "programs", "fetches"})
 EXEC_KEYS_BY_PLANE: Dict[str, FrozenSet[str]] = {
-    "plain": frozenset({"dispatch_ns"}),
-    "packed_vectorized": frozenset({"embed_ns", "block_ns", "head_ns"}),
-    "packed_pipelined": frozenset({"embed_ns", "mixer_ns", "moe_ns",
-                                   "stage_ns", "head_ns"}),
+    "plain": frozenset({"dispatch_ns", "programs"}),
+    "packed_vectorized": _PACKED_EXEC_KEYS | {"block_ns"},
+    "packed_pipelined": _PACKED_EXEC_KEYS | {"moe_ns", "stage_ns"},
 }
 
 REQUEST_KEYS = frozenset({
     "submitted", "finished", "queue_wait_steps", "gen_tokens",
-})
-
-ROOFLINE_KEYS = frozenset({
-    "hw", "windows", "window_steps", "measured_tok_s", "predicted_tok_s",
-    "delta_ratio", "measured_h2d_bytes_per_token",
-    "naive_h2d_bytes_per_token", "h2d_savings_ratio", "context_len",
-    # per-layer-kind state-plane traffic (DESIGN.md §12): fixed-size
-    # recurrent carries (read+write, flat in context) and the shared
-    # encoder-KV cross-read — both set at attach time per config
-    "rec_state_bytes_per_token", "enc_kv_read_bytes_per_token",
-    # prefix-reuse + preemption traffic (DESIGN.md §13): cumulative KV
-    # swap bytes normalized by decode tokens, and the cumulative prompt
-    # tokens whose prefill a prefix hit skipped
-    "kv_swap_bytes_per_token", "prefix_hit_tokens",
 })
 
 SPEC_KEYS = frozenset({
@@ -146,7 +141,7 @@ HISTOGRAM_FIELDS = frozenset({"count", "sum", "min", "max", "p50", "p95",
 
 def expected_namespaces(*, kv_layout: str = "dense", offloaded: bool = False,
                         timing: bool = True, plane: str = "plain",
-                        roofline: bool = True, speculative: bool = False,
+                        speculative: bool = False,
                         prefix_cache: bool = False, kv_host: bool = False,
                         faults: bool = True
                         ) -> Dict[str, FrozenSet[str]]:
@@ -172,6 +167,4 @@ def expected_namespaces(*, kv_layout: str = "dense", offloaded: bool = False,
         out["step"] = STEP_KEYS
         out["request"] = REQUEST_KEYS
         out["exec"] = EXEC_KEYS_BY_PLANE[plane]
-        if roofline:
-            out["roofline"] = ROOFLINE_KEYS
     return out

@@ -6,8 +6,16 @@ formatting until export) and written as the JSON object format
 directly.  Two process tracks:
 
 * ``pid=0`` **engine** — ``tid=0`` carries the per-step phase spans
-  (plan / chunks / dispatch / sync / sample / host nested under each
-  ``step`` span by containment), ``tid=1`` the executor dispatch detail;
+  (plan / chunk / dispatch / sync / sample / host nested under each
+  ``step <i>`` span by containment; the step span's args count its
+  decode rows, prefill chunks, jitted programs and device→host fetches),
+  ``tid=1`` one span per executor dispatch site — ``exec.embed``,
+  ``exec.mixer``, ``exec.moe``, ``exec.stage``, ``exec.block``,
+  ``exec.chunk_moe``, ``exec.head`` (a jitted call with its argument
+  preparation), ``exec.state`` (a layer's state slice or write-back),
+  ``engine.route_fetch`` (the step's route-id fetch), ``exec.dispatch``
+  (the plain plane's one program), with ``{"layer": l}`` where a layer
+  applies — each inside its step's ``dispatch`` or ``chunk`` phase;
 * ``pid=1`` **requests** — one thread per request id, carrying that
   request's lifecycle: ``submit`` instant → ``queue_wait`` span →
   ``prefill[lo:hi)`` span per admission chunk → one ``decode`` span
@@ -21,7 +29,10 @@ construction; ``ts``/``dur`` are microseconds as the format requires.
 Spans measure *host-side dispatch-to-return* intervals — device work
 dispatched asynchronously shows up in the step's ``sync`` phase (the
 point the engine blocks fetching sampled tokens), never as an extra
-device synchronization.
+device synchronization.  The engine track's spans are
+``repro.obs.Span`` s, which also annotate a running profile
+(``engine.<phase>``, and the ``tid=1`` names above) on the device
+trace's clock.
 """
 from __future__ import annotations
 

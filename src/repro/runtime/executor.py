@@ -31,7 +31,18 @@ cache does not manage, so chunking adds zero pool traffic.
 All programs go through ``transformer.cached_jit`` under config-keyed
 names, so every engine and every Executor instance of the same
 (cfg, plane, mode) shares one compiled program per process
-(``cached_jit_stats`` asserts this in the tests).
+(``cached_jit_stats`` asserts this in the tests).  Each packed-plane
+program is a named function (``embed``, ``head``, ``packed_mixer``,
+``packed_moe``, ``packed_stage``, ``packed_blk``, ``packed_blk_plain``,
+``packed_chunk_moe``, ``packed_chunk_moe_ids``), so its XLA module reads
+``jit_<name>`` in a device trace.
+
+With an observer attached (:meth:`Executor.set_observer`,
+``repro.obs.ExecSpans``) every dispatch site of the packed planes sits
+in its own span: the jitted call with its argument preparation
+(``exec.embed`` / ``exec.mixer`` / ``exec.moe`` / ``exec.stage`` /
+``exec.block`` / ``exec.chunk_moe`` / ``exec.head``) and each eager
+per-layer state slice or write-back (``exec.state``).
 """
 from __future__ import annotations
 
@@ -77,8 +88,8 @@ class Executor:
         self.store = store
         self.fused = fused
         self.vectorized = vectorized
-        # optional dispatch-phase observer (repro.obs.ExecPhases);
-        # host-side timestamps around dispatches only — never a device
+        # optional dispatch-site observer (repro.obs.ExecSpans);
+        # host-side spans around dispatches only — never a device
         # sync.  Executors can be shared across engines (the offload
         # engine hands its decoder to ContinuousEngine), so the LAST
         # attached observer wins.
@@ -107,12 +118,15 @@ class Executor:
                     self.moe_ordinal[l] = len(self.moe_ordinal)
             self._layer_p = [T.layer_params(params, cfg, l)
                              for l in range(cfg.n_layers)]
-            self._jit_embed = T.cached_jit(
-                ("embed", cfg), lambda: jax.jit(
-                    lambda p, t: T.embed_tokens(p, cfg, t)))
-            self._jit_head = T.cached_jit(
-                ("head", cfg), lambda: jax.jit(
-                    lambda p, x: T.apply_head(p, cfg, x)))
+            def embed(p, t):
+                return T.embed_tokens(p, cfg, t)
+
+            def head(p, x):
+                return T.apply_head(p, cfg, x)
+            self._jit_embed = T.cached_jit(("embed", cfg),
+                                           lambda: jax.jit(embed))
+            self._jit_head = T.cached_jit(("head", cfg),
+                                          lambda: jax.jit(head))
             # mode key: packed-block executables are shared across
             # executor instances with identical config+flags
             self._mode = (cfg, spec, fused, self.pipelined, vectorized)
@@ -120,10 +134,11 @@ class Executor:
 
     # ------------------------------------------------------------------
     def set_observer(self, obs) -> None:
-        """Attach (or detach with ``None``) the dispatch-phase observer
-        for :meth:`decode` / :meth:`decode_sampled` — an object with
-        ``begin()`` / ``mark(phase)`` whose phases match this plane's
-        ``repro.obs.schema.EXEC_KEYS_BY_PLANE`` entry."""
+        """Attach (or detach with ``None``) the dispatch-site observer
+        for :meth:`decode` / :meth:`decode_sampled` and the packed
+        prefill chunks — an object with ``begin(name, layer)`` /
+        ``end(programs)`` (``repro.obs.ExecSpans``) whose sites match
+        this plane's ``repro.obs.schema.EXEC_KEYS_BY_PLANE`` entry."""
         self._obs = obs
 
     def set_fault_injector(self, inj, *, max_retries: int = 2,
@@ -248,20 +263,22 @@ class Executor:
             fused, vectorized = self.fused, self.vectorized
             if parse_block(kind)[1] == "moe":
                 def make():
-                    fn = lambda p, x, st, pos, store, ps, lm, routers, \
-                        act, pages: T.decode_block_packed(
+                    def packed_blk(p, x, st, pos, store, ps, lm, routers,
+                                   act, pages):
+                        return T.decode_block_packed(
                             p, cfg, kind, x, st, pos, store, ps, lm,
                             routers, lookahead=spec.lookahead,
                             n_spec=spec.num_speculative, fused=fused,
                             active=act, vectorized=vectorized, pages=pages)
-                    return jax.jit(fn, donate_argnums=(5,))
+                    return jax.jit(packed_blk, donate_argnums=(5,))
                 key = ("packed_blk", self._mode, kind)
             else:
                 def make():
-                    fn = lambda p, x, st, pos, pages, act: T._block_decode(
-                        p, cfg, kind, x, st, pos, moe_mode="gather",
-                        pages=pages, active=act)
-                    return jax.jit(fn)
+                    def packed_blk_plain(p, x, st, pos, pages, act):
+                        return T._block_decode(
+                            p, cfg, kind, x, st, pos, moe_mode="gather",
+                            pages=pages, active=act)
+                    return jax.jit(packed_blk_plain)
                 # a non-MoE block's program depends only on (cfg, kind) —
                 # identical across offload modes
                 key = ("packed_blk_plain", cfg, kind)
@@ -272,13 +289,12 @@ class Executor:
         key = ("mixer", kind)
         if key not in self._blk:
             cfg = self.cfg
-            self._blk[key] = T.cached_jit(
-                ("packed_mixer", cfg, kind),
-                lambda: jax.jit(
-                    lambda p, x, st, pos, pages, act:
-                        T.decode_block_packed_mixer(
-                            p, cfg, kind, x, st, pos, pages=pages,
-                            active=act)))
+
+            def packed_mixer(p, x, st, pos, pages, act):
+                return T.decode_block_packed_mixer(
+                    p, cfg, kind, x, st, pos, pages=pages, active=act)
+            self._blk[key] = T.cached_jit(("packed_mixer", cfg, kind),
+                                          lambda: jax.jit(packed_mixer))
         return self._blk[key]
 
     def _moe_blk(self):
@@ -287,11 +303,11 @@ class Executor:
             fused, vectorized = self.fused, self.vectorized
 
             def make():
-                fn = lambda p, x, h2, store, ps, lm, act: \
-                    T.decode_block_packed_moe(
+                def packed_moe(p, x, h2, store, ps, lm, act):
+                    return T.decode_block_packed_moe(
                         p, cfg, x, h2, store, ps, lm, fused=fused,
                         vectorized=vectorized, active=act)
-                return jax.jit(fn, donate_argnums=(4,))
+                return jax.jit(packed_moe, donate_argnums=(4,))
             self._blk["moe_ffn"] = T.cached_jit(("packed_moe", self._mode),
                                                 make)
         return self._blk["moe_ffn"]
@@ -302,12 +318,12 @@ class Executor:
             vectorized = self.vectorized
 
             def make():
-                def fn(store, ps, tgt, hidden, routers):
+                def packed_stage(store, ps, tgt, hidden, routers):
                     pred = speculative.predict_experts(
                         routers[tgt], hidden, n_spec)[0]
                     return EP.stage(store, ps, tgt, pred, True,
                                     vectorized=vectorized)
-                return jax.jit(fn, donate_argnums=(1,))
+                return jax.jit(packed_stage, donate_argnums=(1,))
             self._blk["stage"] = T.cached_jit(("packed_stage", self._mode),
                                               make)
         return self._blk["stage"]
@@ -319,13 +335,13 @@ class Executor:
             cfg, fused = self.cfg, self.fused
 
             def make():
-                def fn(p, x, h2, store, lm):
+                def packed_chunk_moe(p, x, h2, store, lm):
                     B, C, D = h2.shape
                     y2d, _ = M.moe_apply_packed_stream(
                         p["moe"], cfg, h2.reshape(B * C, D), store, lm,
                         fused=fused)
                     return x + y2d.reshape(B, C, D)
-                return jax.jit(fn)
+                return jax.jit(packed_chunk_moe)
             self._blk["chunk_moe"] = T.cached_jit(
                 ("packed_chunk_moe", cfg, fused), make)
         return self._blk["chunk_moe"]
@@ -340,16 +356,55 @@ class Executor:
             cfg, fused = self.cfg, self.fused
 
             def make():
-                def fn(p, x, h2, store, lm):
+                def packed_chunk_moe_ids(p, x, h2, store, lm):
                     B, C, D = h2.shape
                     y2d, info = M.moe_apply_packed_stream(
                         p["moe"], cfg, h2.reshape(B * C, D), store, lm,
                         fused=fused)
                     return x + y2d.reshape(B, C, D), info["ids"]
-                return jax.jit(fn)
+                return jax.jit(packed_chunk_moe_ids)
             self._blk["chunk_moe_ids"] = T.cached_jit(
                 ("packed_chunk_moe_ids", cfg, fused), make)
         return self._blk["chunk_moe_ids"]
+
+    # ------------------------------------------------------------------
+    # packed-plane sites shared by decode and prefill chunks, each in
+    # its span when an observer is attached
+    def _embed(self, tokens):
+        obs = self._obs
+        if obs is not None:
+            obs.begin("exec.embed")
+        x = self._jit_embed(self.params, tokens)
+        if obs is not None:
+            obs.end(1)
+        return x
+
+    def _head(self, x):
+        obs = self._obs
+        if obs is not None:
+            obs.begin("exec.head")
+        logits = self._jit_head(self.params, x)
+        if obs is not None:
+            obs.end(1)
+        return logits
+
+    def _state_layer(self, state, l: int):
+        obs = self._obs
+        if obs is not None:
+            obs.begin("exec.state", l)
+        st_l = T.decode_state_layer(state, self.cfg, l)
+        if obs is not None:
+            obs.end()
+        return st_l
+
+    def _set_state_layer(self, state, l: int, st_l):
+        obs = self._obs
+        if obs is not None:
+            obs.begin("exec.state", l)
+        state = T.set_decode_state_layer(state, self.cfg, l, st_l)
+        if obs is not None:
+            obs.end()
+        return state
 
     # ------------------------------------------------------------------
     def decode(self, state, tokens, pstate=None, active=None, *,
@@ -372,24 +427,20 @@ class Executor:
         rows are idle slots or chunked admissions mid-fill.
         """
         obs = self._obs
-        if obs is not None:
-            obs.begin()
         if not self.packed:
+            if obs is not None:
+                obs.begin("exec.dispatch")
             if collect_info:
                 logits, state, infos = self._plain_step(True)(
                     self.params, state, tokens, active)
-                if obs is not None:
-                    obs.mark("dispatch")
-                return logits, state, None, infos
-            logits, state = self._plain_step(False)(
-                self.params, state, tokens, active)
+            else:
+                logits, state = self._plain_step(False)(
+                    self.params, state, tokens, active)
+                infos = None
             if obs is not None:
-                obs.mark("dispatch")
-            return logits, state, None, None
-        cfg = self.cfg
-        x = self._jit_embed(self.params, tokens)
-        if obs is not None:
-            obs.mark("embed")
+                obs.end(1)
+            return logits, state, None, infos
+        x = self._embed(tokens)
         pos = state["pos"]
         pages = state.get("pages")
         B = int(tokens.shape[0])
@@ -398,63 +449,73 @@ class Executor:
         # block applies inside jit via moe_apply_packed's T == 1 check
         speculate = (self.pipelined and self.spec.num_speculative > 0
                      and B * int(tokens.shape[1]) == 1)
+        # a whole block (or a non-MoE layer) is the pipelined plane's
+        # mixer site and the vectorized plane's block site
+        blk_site = "exec.mixer" if self.pipelined else "exec.block"
+        moe_site = "exec.moe" if self.pipelined else "exec.block"
         route_ids = []
         for l, kind in enumerate(self.kinds):
-            st_l = T.decode_state_layer(state, cfg, l)
-            if l in self.moe_ordinal:
+            st_l = self._state_layer(state, l)
+            lp = self._layer_p[l]
+            if l not in self.moe_ordinal:
+                if obs is not None:
+                    obs.begin(blk_site, l)
+                x, st_l, _ = self._decode_blk(kind)(
+                    lp, x, st_l, pos, pages, active)
+                if obs is not None:
+                    obs.end(1)
+            elif self._fetch_faulted():
+                # retry ladder exhausted (DESIGN.md §14): degrade this
+                # layer to store-direct streaming — bitwise the pool
+                # path's activations (shared _packed_compute), zero pool
+                # traffic, no speculative staging
+                if obs is not None:
+                    obs.begin(blk_site, l)
+                x, st_l, h2 = self._mixer_blk(kind)(
+                    lp, x, st_l, pos, pages, active)
+                if obs is not None:
+                    obs.end(1)
+                    obs.begin(moe_site, l)
                 lm = jnp.asarray(self.moe_ordinal[l], jnp.int32)
-                if self._fetch_faulted():
-                    # retry ladder exhausted (DESIGN.md §14): degrade
-                    # this layer to store-direct streaming — bitwise the
-                    # pool path's activations (shared _packed_compute),
-                    # zero pool traffic, no speculative staging
-                    x, st_l, h2 = self._mixer_blk(kind)(
-                        self._layer_p[l], x, st_l, pos, pages, active)
+                x, ids = self._chunk_moe_ids_blk()(lp, x, h2, self.store, lm)
+                if obs is not None:
+                    obs.end(1)
+                route_ids.append(ids)
+            elif self.pipelined:
+                if obs is not None:
+                    obs.begin("exec.mixer", l)
+                x, st_l, h2 = self._mixer_blk(kind)(
+                    lp, x, st_l, pos, pages, active)
+                if obs is not None:
+                    obs.end(1)
+                    obs.begin("exec.moe", l)
+                lm = jnp.asarray(self.moe_ordinal[l], jnp.int32)
+                x, pstate, info = self._moe_blk()(
+                    lp, x, h2, self.store, pstate, lm, active)
+                if obs is not None:
+                    obs.end(1)
+                tgt = self.moe_ordinal[l] + self.spec.lookahead
+                if speculate and tgt < self.n_moe_layers:
                     if obs is not None:
-                        obs.mark("mixer" if self.pipelined else "block")
-                    x, ids = self._chunk_moe_ids_blk()(
-                        self._layer_p[l], x, h2, self.store, lm)
+                        obs.begin("exec.stage", l)
+                    pstate = self._stage_blk()(
+                        self.store, pstate, jnp.asarray(tgt, jnp.int32),
+                        info["hidden_pre_moe"], self.routers)
                     if obs is not None:
-                        obs.mark("moe" if self.pipelined else "block")
-                    route_ids.append(ids)
-                    state = T.set_decode_state_layer(state, cfg, l, st_l)
-                    continue
-                if self.pipelined:
-                    x, st_l, h2 = self._mixer_blk(kind)(
-                        self._layer_p[l], x, st_l, pos, pages, active)
-                    if obs is not None:
-                        obs.mark("mixer")
-                    x, pstate, info = self._moe_blk()(
-                        self._layer_p[l], x, h2, self.store, pstate, lm,
-                        active)
-                    if obs is not None:
-                        obs.mark("moe")
-                    tgt = self.moe_ordinal[l] + self.spec.lookahead
-                    if speculate and tgt < self.n_moe_layers:
-                        pstate = self._stage_blk()(
-                            self.store, pstate,
-                            jnp.asarray(tgt, jnp.int32),
-                            info["hidden_pre_moe"], self.routers)
-                        if obs is not None:
-                            obs.mark("stage")
-                else:
-                    x, st_l, pstate, info = self._decode_blk(kind)(
-                        self._layer_p[l], x, st_l, pos, self.store, pstate,
-                        lm, self.routers, active, pages)
-                    if obs is not None:
-                        obs.mark("block")
+                        obs.end(1)
                 route_ids.append(info["route"]["ids"])
             else:
-                x, st_l, _ = self._decode_blk(kind)(
-                    self._layer_p[l], x, st_l, pos, pages, active)
                 if obs is not None:
-                    # non-MoE dispatch: the pipelined plane's mixer bucket,
-                    # the vectorized plane's block bucket
-                    obs.mark("mixer" if self.pipelined else "block")
-            state = T.set_decode_state_layer(state, cfg, l, st_l)
-        logits = self._jit_head(self.params, x)
-        if obs is not None:
-            obs.mark("head")
+                    obs.begin("exec.block", l)
+                lm = jnp.asarray(self.moe_ordinal[l], jnp.int32)
+                x, st_l, pstate, info = self._decode_blk(kind)(
+                    lp, x, st_l, pos, self.store, pstate, lm,
+                    self.routers, active, pages)
+                if obs is not None:
+                    obs.end(1)
+                route_ids.append(info["route"]["ids"])
+            state = self._set_state_layer(state, l, st_l)
+        logits = self._head(x)
         # decode is the C=1 case of a chunk; a C=k+1 verify chunk
         # (speculative decoding, DESIGN.md §11) advances by its width
         C = int(tokens.shape[1])
@@ -473,14 +534,43 @@ class Executor:
         assert not self.packed, "packed decode returns logits; sample host-side"
         obs = self._obs
         if obs is not None:
-            obs.begin()
+            obs.begin("exec.dispatch")
         out = self._plain_step_sampled(collect_info, greedy)(
             self.params, state, tokens, active)
         if obs is not None:
-            obs.mark("dispatch")
+            obs.end(1)
         return out
 
     # ------------------------------------------------------------------
+    def _chunk_layers(self, state, x, pos, pages):
+        """A prompt chunk's per-layer loop on the packed planes: mixer +
+        store-streamed chunk MoE per MoE layer (no pool state), the
+        whole block otherwise.  Returns (x, state)."""
+        obs = self._obs
+        blk_site = "exec.mixer" if self.pipelined else "exec.block"
+        for l, kind in enumerate(self.kinds):
+            st_l = self._state_layer(state, l)
+            lp = self._layer_p[l]
+            if l in self.moe_ordinal:
+                if obs is not None:
+                    obs.begin("exec.mixer", l)
+                x, st_l, h2 = self._mixer_blk(kind)(
+                    lp, x, st_l, pos, pages, None)
+                if obs is not None:
+                    obs.end(1)
+                    obs.begin("exec.chunk_moe", l)
+                lm = jnp.asarray(self.moe_ordinal[l], jnp.int32)
+                x = self._chunk_moe_blk()(lp, x, h2, self.store, lm)
+            else:
+                if obs is not None:
+                    obs.begin(blk_site, l)
+                x, st_l, _ = self._decode_blk(kind)(
+                    lp, x, st_l, pos, pages, None)
+            if obs is not None:
+                obs.end(1)
+            state = self._set_state_layer(state, l, st_l)
+        return x, state
+
     def prefill_chunk(self, state, tokens, pstate=None):
         """Process prompt chunk ``tokens`` (B, C) at the rows' current
         positions: KV written at ``pos .. pos+C−1``, ``pos`` advances by
@@ -490,23 +580,10 @@ class Executor:
             logits, state = self._plain_step(False)(
                 self.params, state, tokens, None)
             return logits, state, pstate
-        cfg = self.cfg
-        x = self._jit_embed(self.params, tokens)
+        x = self._embed(tokens)
         pos = state["pos"]
-        pages = state.get("pages")
-        for l, kind in enumerate(self.kinds):
-            st_l = T.decode_state_layer(state, cfg, l)
-            if l in self.moe_ordinal:
-                lm = jnp.asarray(self.moe_ordinal[l], jnp.int32)
-                x, st_l, h2 = self._mixer_blk(kind)(
-                    self._layer_p[l], x, st_l, pos, pages, None)
-                x = self._chunk_moe_blk()(
-                    self._layer_p[l], x, h2, self.store, lm)
-            else:
-                x, st_l, _ = self._decode_blk(kind)(
-                    self._layer_p[l], x, st_l, pos, pages, None)
-            state = T.set_decode_state_layer(state, cfg, l, st_l)
-        logits = self._jit_head(self.params, x)
+        x, state = self._chunk_layers(state, x, pos, state.get("pages"))
+        logits = self._head(x)
         state = dict(state, pos=pos + tokens.shape[1])
         return logits, state, pstate
 
@@ -521,24 +598,12 @@ class Executor:
         slot_t = jnp.asarray(slot, jnp.int32)
         if not self.packed:
             return self._row_chunk_step()(self.params, state, tokens, slot_t)
-        cfg = self.cfg
-        x = self._jit_embed(self.params, tokens)
+        x = self._embed(tokens)
         pos_row = jax.lax.dynamic_slice(state["pos"], (slot_t,), (1,))
         pages_row = jax.lax.dynamic_slice(
             state["pages"], (slot_t, 0), (1, state["pages"].shape[1]))
-        for l, kind in enumerate(self.kinds):
-            st_l = T.decode_state_layer(state, cfg, l)
-            if l in self.moe_ordinal:
-                lm = jnp.asarray(self.moe_ordinal[l], jnp.int32)
-                x, st_l, h2 = self._mixer_blk(kind)(
-                    self._layer_p[l], x, st_l, pos_row, pages_row, None)
-                x = self._chunk_moe_blk()(
-                    self._layer_p[l], x, h2, self.store, lm)
-            else:
-                x, st_l, _ = self._decode_blk(kind)(
-                    self._layer_p[l], x, st_l, pos_row, pages_row, None)
-            state = T.set_decode_state_layer(state, cfg, l, st_l)
-        logits = self._jit_head(self.params, x)
+        x, state = self._chunk_layers(state, x, pos_row, pages_row)
+        logits = self._head(x)
         state = dict(state, pos=jax.lax.dynamic_update_slice(
             state["pos"], pos_row + tokens.shape[1], (slot_t,)))
         return logits, state

@@ -235,8 +235,8 @@ class ContinuousEngine:
         CLI ablation path).
 
         ``telemetry``: a :class:`repro.obs.Telemetry` turns on the
-        unified telemetry plane (DESIGN.md §10) — per-step phase timing,
-        per-request span tracing and roofline accounting.  Default is
+        unified telemetry plane (DESIGN.md §10) — per-step phase spans,
+        per-request and per-dispatch-site span tracing.  Default is
         ``Telemetry.off()``: only the pull-time collectors that back
         :meth:`metrics` / :meth:`stats` exist, the decode loop carries
         zero instrumentation, and generated tokens are bitwise identical
@@ -380,8 +380,8 @@ class ContinuousEngine:
         self._rng = jax.random.key(seed)
         # telemetry plane (DESIGN.md §10): collectors are registered even
         # in the off mode (they only run at snapshot time and back the
-        # legacy stats() projection); timing/tracing/roofline attach only
-        # when an enabled Telemetry is passed in
+        # legacy stats() projection); timing/tracing attach only when an
+        # enabled Telemetry is passed in
         self.obs = telemetry if telemetry is not None else Telemetry.off()
         reg = self.obs.registry
         reg.register_collector("engine", self._engine_metrics)
@@ -399,22 +399,12 @@ class ContinuousEngine:
         if self.obs.timing:
             self.obs.declare_step_schema()
             self.obs.declare_request_schema()
-            # executors can be shared (the offload engine hands over its
-            # decoder) — the last engine to attach an observer wins
-            self._exec.set_observer(self.obs.exec_observer(self._exec.plane))
-            if offload is not None:
-                q = offload.size_report is not None
-                self.obs.attach_roofline(
-                    cfg,
-                    expert_bits=offload.spec.expert_bits if q else 16,
-                    attn_bits=offload.spec.attn_bits if q else 16,
-                    expert_bytes=offload.expert_bytes,
-                    # the same tiny counts array stats() already fetches,
-                    # read once per roofline window — never per step
-                    h2d_counts_fn=lambda: tuple(
-                        int(c) for c in np.asarray(self._pstate.counts)))
-            else:
-                self.obs.attach_roofline(cfg)
+        # dispatch-site spans (None with timing off); executors can be
+        # shared (the offload engine hands over its decoder) — the last
+        # engine to attach an observer wins
+        self._xobs = self.obs.exec_observer(self._exec.plane)
+        if self._xobs is not None:
+            self._exec.set_observer(self._xobs)
         # --------------------------------------------------------------
         # token-level draft-and-verify decoding (DESIGN.md §11)
         self.spec_k = int(num_draft_tokens or 0)
@@ -646,8 +636,6 @@ class ContinuousEngine:
             return
         self._prefills_skipped += 1
         self._prefix_hit_tokens += base
-        if self.obs.roofline is not None:
-            self.obs.roofline.add_prefix_hit(base)
 
     def _prefix_insert(self, tokens, slot: int) -> None:
         """Index the slot's full prompt pages after its prefill finished
@@ -702,9 +690,6 @@ class ContinuousEngine:
         slot = req.slot
         n_live = self.kv.length(slot)
         blob = self.kv.swap_out(slot)  # None => drop + recompute
-        if blob is not None and self.obs.roofline is not None:
-            self.obs.roofline.add_swap_bytes(
-                blob["n_pages"] * self.kv.page_nbytes())
         self.kv.release(slot)
         self.sched.preempt(req)
         self._swap_seq += 1
@@ -749,9 +734,6 @@ class ContinuousEngine:
                 if not self._make_room(req):
                     return False
             slot = self.kv.swap_in(req.rid, sw.blob, sw.n_tokens + 1)
-            if self.obs.roofline is not None:
-                self.obs.roofline.add_swap_bytes(
-                    sw.blob["n_pages"] * self.kv.page_nbytes())
             req.slot = slot
             self.sched.resume(req)
             self.tokens[slot, 0] = sw.next_tok
@@ -1067,24 +1049,27 @@ class ContinuousEngine:
             # (checked right below) exactly like a real device hiccup
             time.sleep(self.faults.stall_ms() / 1e3)
         self._expire_deadlines()
+        # the step's phases: each span opens where its phase starts
         st = self.obs.step_begin(self.step_count)
+        if st is not None:
+            st.phase("plan")
         plan = self._plan()
         if st is not None:
-            st.mark("plan")
+            st.phase("chunk")
         finished = self._run_chunks(plan.chunks)
-        if st is not None:
-            st.mark("chunk")
         # unchunked admission keeps the legacy timing: a request admitted
         # this step decodes this step.  Budgeted (chunked) steps decode
         # exactly the planned rows so the budget accounting stays exact.
         rows = (self._decode_rows() if self.budget is None
                 else plan.decode_rows)
         if not rows:
+            self.obs.step_end(st, n_chunks=len(plan.chunks))
             if plan.chunks:
                 self.step_count += 1
                 self._audit_step()
-            self.obs.step_end(st, n_chunks=len(plan.chunks))
             return finished
+        if st is not None:
+            st.phase("dispatch")
         reqs = sorted((r for r in self.sched.running
                        if r.slot in set(rows)), key=lambda r: r.slot)
         if self._spec_metrics is not None:
@@ -1125,8 +1110,7 @@ class ContinuousEngine:
                 step_state, jnp.asarray(self.tokens), self._pstate,
                 jnp.asarray(active))
             if self._collect:
-                self.usage.update([np.asarray(i) for i in route_ids],
-                                  rows=rows)
+                self._route_fetch(route_ids, rows)
             nxt_dev = (jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
                        if self._greedy and not quar else logits[:, -1])
         elif quar:
@@ -1154,7 +1138,7 @@ class ContinuousEngine:
             else:
                 nxt_dev, state = out
         if st is not None:
-            st.mark("dispatch")
+            st.phase("sync" if quar or self._greedy else "sample")
         if self.paged:
             self.kv.adopt(state)
             for r in rows:
@@ -1167,7 +1151,7 @@ class ContinuousEngine:
             # (copied: the poison write needs a writable buffer)
             last = np.asarray(nxt_dev).copy()
             if st is not None:
-                st.mark("sync")
+                st.phase("host" if self._greedy else "sample")
             reqs = self._quarantine(last, reqs, finished)
             nxt = np.zeros((self.max_slots,), np.int32)
             if reqs:
@@ -1178,22 +1162,18 @@ class ContinuousEngine:
                 else:
                     nxt[srows] = self._sample_rows(
                         jnp.asarray(last[srows]), reqs)
-                    if st is not None:
-                        st.mark("sample")
         elif self._greedy:
             # the step's one blocking device fetch — everything the
             # device is still computing lands in this phase
             nxt = np.asarray(nxt_dev)
-            if st is not None:
-                st.mark("sync")
         else:
             nxt = self._sample_rows(
                 jnp.asarray(nxt_dev)[np.asarray(rows)], reqs)
             full = np.zeros((self.max_slots,), np.int32)
             full[np.asarray(rows)] = nxt
             nxt = full
-            if st is not None:
-                st.mark("sample")
+        if st is not None:
+            st.phase("host")
         for req in reqs:
             t = int(nxt[req.slot])
             req.emit(t)
@@ -1208,14 +1188,21 @@ class ContinuousEngine:
         self.step_count += 1
         self._audit_step()
         if st is not None:
-            st.mark("host")
-            # live context from host-side request records — never a
-            # device fetch (the dense manager's pos lives on device)
-            ctx = (sum(len(r.prompt) + len(r.generated) for r in reqs)
-                   / max(1, len(reqs)))
             self.obs.step_end(st, n_decode=len(reqs),
-                              n_chunks=len(plan.chunks), context_len=ctx)
+                              n_chunks=len(plan.chunks))
         return finished
+
+    def _route_fetch(self, route_ids, rows) -> None:
+        """Fetch the packed decode's route ids to the host (one blocking
+        device→host copy per MoE layer) into the usage histogram, inside
+        an ``engine.route_fetch`` span."""
+        xo = self._xobs
+        if xo is not None:
+            xo.begin("engine.route_fetch")
+        ids = [np.asarray(i) for i in route_ids]
+        self.usage.update(ids, rows=rows)
+        if xo is not None:
+            xo.end(fetches=len(ids))
 
     # ------------------------------------------------------------------
     # token-level draft-and-verify decoding (DESIGN.md §11)
@@ -1333,9 +1320,8 @@ class ContinuousEngine:
             if self._collect:
                 # packed route ids are token-major (B*C, K): map each
                 # chunk position back to its slot for the usage histogram
-                tok_rows = [r * C + j for r in rows for j in range(C)]
-                self.usage.update([np.asarray(i) for i in route_ids],
-                                  rows=tok_rows)
+                self._route_fetch(
+                    route_ids, [r * C + j for r in rows for j in range(C)])
         else:
             logits, state, _, infos = self._exec.decode(
                 step_state, jnp.asarray(chunk), active=act_dev,
@@ -1347,7 +1333,7 @@ class ContinuousEngine:
                 tok_rows = [r * C + j for r in rows for j in range(C)]
                 self.usage.update(ids, rows=tok_rows)
         if st is not None:
-            st.mark("dispatch")
+            st.phase("sync")
         if self.paged:
             self.kv.adopt(state)
             for r in rows:
@@ -1357,7 +1343,7 @@ class ContinuousEngine:
         # the round's one blocking fetch: every row's target argmax
         tgt = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
         if st is not None:
-            st.mark("sync")
+            st.phase("host")
         for req in reqs:
             r = req.slot
             emitted, a = verify_round(props[r], tgt[r])
@@ -1406,11 +1392,8 @@ class ContinuousEngine:
         self.step_count += 1
         self._audit_step()
         if st is not None:
-            st.mark("host")
-            ctx = (sum(len(r.prompt) + len(r.generated) for r in reqs)
-                   / max(1, len(reqs)))
             self.obs.step_end(st, n_decode=len(reqs),
-                              n_chunks=len(plan.chunks), context_len=ctx)
+                              n_chunks=len(plan.chunks))
         return finished
 
     def run(self, max_steps: Optional[int] = None) -> List[GenRequest]:
@@ -1488,7 +1471,7 @@ class ContinuousEngine:
     def metrics(self) -> Dict[str, Dict[str, object]]:
         """Namespaced telemetry snapshot ``{namespace: {key: value}}``
         (``repro.obs.schema``) — collectors pull fresh state at call
-        time; timing/roofline namespaces appear when enabled."""
+        time; timing namespaces appear when enabled."""
         return self.obs.snapshot()
 
     def stats(self) -> Dict[str, float]:

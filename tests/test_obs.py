@@ -16,8 +16,7 @@ from repro.obs import Telemetry, flatten_legacy
 from repro.obs.registry import Histogram, MetricsRegistry
 from repro.obs.schema import (EXEC_KEYS_BY_PLANE, HISTOGRAM_FIELDS,
                               JIT_KEYS, OFFLOAD_KEYS, REQUEST_KEYS,
-                              ROOFLINE_KEYS, SPEC_KEYS,
-                              expected_namespaces)
+                              SPEC_KEYS, expected_namespaces)
 from repro.obs.tracing import PID_REQUESTS, Tracer
 from repro.serving.engine import ContinuousEngine
 from repro.serving.sampler import SamplerConfig
@@ -131,7 +130,7 @@ def test_continuous_snapshot_schema(tiny_moe_cfg, tiny_moe_params, kv_page):
                           kv_page=kv_page)
     _assert_schema(eng.metrics(),
                    kv_layout="paged" if kv_page else "dense",
-                   timing=True, plane="plain", roofline=True)
+                   timing=True, plane="plain")
     assert set(eng.metrics()["step"]["wall_ms"]) == HISTOGRAM_FIELDS
 
 
@@ -154,13 +153,9 @@ def test_offloaded_continuous_snapshot_schema(tiny_moe_cfg,
                           Telemetry(timing=True, trace=True), offload=off)
     snap = eng.metrics()
     _assert_schema(snap, kv_layout="dense", offloaded=True, timing=True,
-                   plane="packed_pipelined", roofline=True)
-    # the offload namespace carries real traffic and the roofline saw it
+                   plane="packed_pipelined")
+    # the offload namespace carries real traffic
     assert snap["offload"]["demand_loads"] + snap["offload"]["spec_loads"] > 0
-    assert snap["roofline"]["windows"] >= 1
-    assert snap["roofline"]["measured_tok_s"] > 0
-    assert snap["roofline"]["h2d_savings_ratio"] > 1.0, \
-        "expert streaming should beat the naive all-experts-every-layer bound"
     assert "offload_hits" in eng.stats()
 
 
@@ -181,7 +176,7 @@ def test_speculative_snapshot_schema(tiny_moe_cfg, tiny_moe_params,
                           num_draft_tokens=3)
     snap = eng.metrics()
     _assert_schema(snap, kv_layout="dense", timing=True, plane="plain",
-                   roofline=True, speculative=True)
+                   speculative=True)
     spec = snap["spec"]
     assert set(spec) == SPEC_KEYS
     assert spec["rounds"] > 0
@@ -196,7 +191,7 @@ def test_speculative_snapshot_schema(tiny_moe_cfg, tiny_moe_params,
     eng.obs.write_metrics(mpath, {
         "engine": "continuous", "arch": tiny_moe_cfg.name,
         "kv_layout": "dense", "offloaded": False, "timing": True,
-        "plane": "plain", "roofline": True, "speculative": True})
+        "plane": "plain", "speculative": True})
     assert _load_checker().check_metrics(mpath) == []
 
 
@@ -212,10 +207,9 @@ def test_offload_engine_snapshot_schema(tiny_moe_cfg, tiny_moe_params):
                        quantized=True, telemetry=telem)
     on.generate(prompt, 4)
     snap = on.metrics()
-    assert set(snap) == {"offload", "jit", "request", "exec", "roofline"}
+    assert set(snap) == {"offload", "jit", "request", "exec"}
     assert set(snap["request"]) == REQUEST_KEYS
     assert set(snap["exec"]) == EXEC_KEYS_BY_PLANE["packed_pipelined"]
-    assert set(snap["roofline"]) == ROOFLINE_KEYS
     assert set(snap["jit"]) == JIT_KEYS
     assert snap["request"]["finished"] == 1
 
@@ -236,15 +230,156 @@ def test_parity_telemetry_on_off(tiny_moe_cfg, tiny_moe_params, mode):
     assert on_toks == off_toks, f"{mode}: telemetry perturbed the tokens"
 
 
-def test_parity_telemetry_on_off_offloaded(tiny_moe_cfg, tiny_moe_params):
+def test_parity_telemetry_on_off_offloaded(tiny_moe_cfg, tiny_moe_params,
+                                           monkeypatch):
+    names = _record_annotations(monkeypatch)
     off_eng = OffloadEngine(tiny_moe_params, tiny_moe_cfg, _offload_spec(),
                             quantized=True)
-    _, base = _run_serving(tiny_moe_cfg, tiny_moe_params, None,
-                           offload=off_eng, seed=11)
+    eng, base = _run_serving(tiny_moe_cfg, tiny_moe_params, None,
+                             offload=off_eng, seed=11)
+    # telemetry off: no span event and no profiler annotation at all
+    assert eng.obs.tracer is None and names == []
     _, on = _run_serving(tiny_moe_cfg, tiny_moe_params,
                          Telemetry(timing=True, trace=True),
                          offload=off_eng, seed=11)
     assert on == base, "telemetry perturbed the offloaded packed plane"
+    assert names, "telemetry on made no annotation"
+
+
+# ----------------------------------------------------------------------
+# spans at the dispatch sites (exec track) and the step phases as spans
+_PHASES = ("plan", "chunk", "dispatch", "sync", "sample", "host")
+
+
+def _record_annotations(monkeypatch):
+    """Replace ``jax.profiler.TraceAnnotation`` with a recorder; returns
+    the list the names it is made with are appended to."""
+    import jax
+    names = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return names
+
+
+def _serve_b1_offloaded(cfg, params, telemetry):
+    """Batch-1 offloaded serving on the packed pipelined plane (speculative
+    staging on), paged KV and chunked prefill: two requests of 4 decode
+    steps each."""
+    off = OffloadEngine(params, cfg, _offload_spec(), quantized=True)
+    eng = ContinuousEngine(params, cfg, max_slots=1, slot_len=40,
+                           eos_id=None, kv_page=8, prefill_chunk=8,
+                           offload=off, telemetry=telemetry)
+    for p in _prompts(cfg, 2, seed=4, lo=9, hi=13):
+        eng.submit(p, 5)
+    eng.run(max_steps=100)
+    return eng, off
+
+
+def _spans(tel, tid):
+    return [(e["ts"], e["ts"] + e["dur"], e["name"], e.get("args", {}))
+            for e in tel.tracer.events
+            if e["ph"] == "X" and e["pid"] == 0 and e["tid"] == tid]
+
+
+def test_exec_spans_nest_in_their_step_phase(tiny_moe_cfg, tiny_moe_params):
+    tel = Telemetry(timing=True, trace=True)
+    eng, off = _serve_b1_offloaded(tiny_moe_cfg, tiny_moe_params, tel)
+    steps = _spans(tel, 0)
+    sites = _spans(tel, 1)
+    assert sites
+    # the step track holds the step spans and the six phases only; no
+    # site span is named as a phase
+    assert {n for _, _, n, _ in steps if not n.startswith("step ")} \
+        <= set(_PHASES)
+    assert all(n.startswith(("exec.", "engine.")) and n not in _PHASES
+               for _, _, n, _ in sites)
+    phases = [(lo, hi, n) for lo, hi, n, _ in steps
+              if n in ("dispatch", "chunk")]
+    for lo, hi, name, args in sites:
+        parent = [n for plo, phi, n in phases if plo <= lo and hi <= phi]
+        assert len(parent) == 1, (name, lo, hi)
+        if name in ("engine.route_fetch", "exec.moe", "exec.stage"):
+            assert parent == ["dispatch"]
+        if name == "exec.chunk_moe":
+            assert parent == ["chunk"]
+        assert ("layer" in args) == (name in (
+            "exec.state", "exec.mixer", "exec.moe", "exec.stage",
+            "exec.chunk_moe"))
+    # sites never overlap: their sum is the part of dispatch they cover
+    ordered = sorted(sites)
+    assert all(a[1] <= b[0] for a, b in zip(ordered, ordered[1:]))
+
+
+def test_step_span_counts_programs_and_fetches(tiny_moe_cfg,
+                                               tiny_moe_params):
+    tel = Telemetry(timing=True, trace=True)
+    eng, off = _serve_b1_offloaded(tiny_moe_cfg, tiny_moe_params, tel)
+    n_moe = off.n_moe_layers
+    sites = _spans(tel, 1)
+    decode_steps = 0
+    total = {"programs": 0, "fetches": 0}
+    for lo, hi, name, args in _spans(tel, 0):
+        if not name.startswith("step "):
+            continue
+        total["programs"] += args["programs"]
+        total["fetches"] += args["fetches"]
+        if args["decode_rows"]:
+            decode_steps += 1
+            stages = sum(1 for s, e, n, _ in sites
+                         if n == "exec.stage" and lo <= s and e <= hi)
+            assert stages == n_moe - 1  # lookahead 1
+            assert args["programs"] == 2 + 2 * n_moe + stages
+            assert args["fetches"] == n_moe  # one route-id array a layer
+    assert decode_steps == 2 * 4
+    snap = tel.snapshot()["exec"]
+    assert snap["programs"] == total["programs"]
+    assert snap["fetches"] == total["fetches"] == decode_steps * n_moe
+    assert set(snap) == EXEC_KEYS_BY_PLANE["packed_pipelined"]
+    for key in ("embed_ns", "mixer_ns", "moe_ns", "stage_ns", "head_ns",
+                "state_ns", "chunk_moe_ns", "route_fetch_ns"):
+        assert snap[key] > 0, key
+    # each counter is the sum of its spans (us in the trace, ns here)
+    mixer_us = sum(e - s for s, e, n, _ in sites if n == "exec.mixer")
+    assert snap["mixer_ns"] / 1e3 == pytest.approx(mixer_us, rel=1e-6)
+
+
+def test_span_annotations_follow_timing(tiny_moe_cfg, tiny_moe_params,
+                                        monkeypatch):
+    names = _record_annotations(monkeypatch)
+    # telemetry off: spans are no-ops, nothing is annotated
+    with Telemetry.off().span("exec.embed"):
+        pass
+    assert names == []
+    # timing without tracing still annotates, and writes no event
+    tel = Telemetry(timing=True, trace=False)
+    _serve_b1_offloaded(tiny_moe_cfg, tiny_moe_params, tel)
+    assert tel.tracer is None
+    got = set(names)
+    assert {"engine." + p for p in ("plan", "chunk", "dispatch", "sync",
+                                    "host")} <= got
+    assert {"exec.embed", "exec.mixer", "exec.moe", "exec.stage",
+            "exec.head", "exec.state", "exec.chunk_moe",
+            "engine.route_fetch"} <= got
+    assert all(n.startswith(("engine.", "exec.")) for n in got)
+    assert not any(n.startswith("bench.") for n in got)
+    # one span, both sinks: the Chrome event and the annotation
+    names.clear()
+    tel = Telemetry(timing=True, trace=True)
+    with tel.span("exec.mixer", 1, layer=3):
+        pass
+    assert names == ["exec.mixer"]
+    ev = tel.tracer.events[-1]
+    assert (ev["name"], ev["pid"], ev["tid"], ev["args"]) == (
+        "exec.mixer", 0, 1, {"layer": 3})
 
 
 def test_parity_offload_engine_generate(tiny_moe_cfg, tiny_moe_params):
@@ -279,7 +414,7 @@ def test_metrics_and_trace_files_pass_ci_checker(tiny_moe_cfg,
     eng.obs.write_metrics(mpath, {
         "engine": "continuous", "arch": tiny_moe_cfg.name,
         "kv_layout": "dense", "offloaded": False, "timing": True,
-        "plane": "plain", "roofline": True})
+        "plane": "plain"})
     eng.obs.write_trace(tpath)
     checker = _load_checker()
     assert checker.check_metrics(mpath) == []

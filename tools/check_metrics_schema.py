@@ -29,7 +29,7 @@ sys.path.insert(0, str(ROOT / "src"))
 
 from repro.obs.schema import (EXEC_KEYS_BY_PLANE, HISTOGRAM_FIELDS,  # noqa: E402
                               JIT_KEYS, OFFLOAD_KEYS, REQUEST_KEYS,
-                              ROOFLINE_KEYS, SCHEMA_VERSION, SPEC_KEYS,
+                              SCHEMA_VERSION, SPEC_KEYS,
                               expected_namespaces)
 
 # histograms serialize as nested dicts; everything else is scalar-ish
@@ -40,6 +40,13 @@ HISTOGRAM_METRICS = {("step", "wall_ms"), ("request", "queue_wait_steps"),
 # span/instant names every traced continuous-serve run must carry
 REQUIRED_TRACE_NAMES = {"submit", "queue_wait", "decode", "finish"}
 
+# the executor track (pid 0, tid 1) carries only dispatch-site spans:
+# ``<track>.<site>`` for every ``<site>_ns`` counter of any plane
+EXEC_SPAN_NAMES = {
+    ("engine." if key == "route_fetch_ns" else "exec.") + key[:-len("_ns")]
+    for keys in EXEC_KEYS_BY_PLANE.values() for key in keys
+    if key.endswith("_ns")}
+
 
 def expected_for_mode(mode):
     """``mode`` descriptor (the dict serve.py embeds) -> exact
@@ -47,7 +54,6 @@ def expected_for_mode(mode):
     engine = mode.get("engine")
     timing = bool(mode.get("timing", False))
     plane = mode.get("plane", "plain")
-    roofline = bool(mode.get("roofline", timing))
     # read via .get: files from before the speculation / prefix-caching
     # PRs carry none of these fields and must keep validating
     speculative = bool(mode.get("speculative", False))
@@ -59,13 +65,13 @@ def expected_for_mode(mode):
         return expected_namespaces(
             kv_layout=mode.get("kv_layout", "dense"),
             offloaded=bool(mode.get("offloaded", False)),
-            timing=timing, plane=plane, roofline=roofline,
+            timing=timing, plane=plane,
             speculative=speculative, prefix_cache=prefix_cache,
             kv_host=kv_host, faults=bool(mode.get("faults", True)))
     if engine == "offload":
         # the batch OffloadEngine has no scheduler/KV-slot plane or step
-        # loop — it carries traffic + jit always, request/exec/roofline
-        # when timing is on, spec when draft-and-verify decoding ran
+        # loop — it carries traffic + jit always, request/exec when
+        # timing is on, spec when draft-and-verify decoding ran
         # (no faults namespace: the fault-injection plane lives in the
         # continuous engine's request lifecycle, DESIGN.md §14)
         out = {"offload": OFFLOAD_KEYS, "jit": JIT_KEYS}
@@ -74,8 +80,6 @@ def expected_for_mode(mode):
         if timing:
             out["request"] = REQUEST_KEYS
             out["exec"] = EXEC_KEYS_BY_PLANE[plane]
-            if roofline:
-                out["roofline"] = ROOFLINE_KEYS
         return out
     raise ValueError(f"unknown mode.engine {engine!r}")
 
@@ -92,8 +96,7 @@ def check_metrics(path: Path):
         errors.append(f"{path}: schema_version {doc['schema_version']} != "
                       f"{SCHEMA_VERSION}")
     mode = doc["mode"]
-    for field in ("engine", "arch", "offloaded", "timing", "plane",
-                  "roofline"):
+    for field in ("engine", "arch", "offloaded", "timing", "plane"):
         if field not in mode:
             errors.append(f"{path}: mode missing {field!r} (got "
                           f"{sorted(mode)})")
@@ -148,6 +151,10 @@ def check_trace(path: Path):
             if ev["ph"] == "X" and ev.get("dur", -1.0) < 0.0:
                 errors.append(f"{path}: event {i} ({ev['name']}) has "
                               f"negative/missing dur")
+            if (ev["ph"] == "X" and (ev["pid"], ev["tid"]) == (0, 1)
+                    and ev["name"] not in EXEC_SPAN_NAMES):
+                errors.append(f"{path}: event {i} ({ev['name']}) on the "
+                              f"executor track is no dispatch site")
     meta = names_by_ph.get("M", set())
     if not {"process_name", "thread_name"} <= meta:
         errors.append(f"{path}: missing process/thread metadata events "
