@@ -249,14 +249,3 @@ class _frozen(dict):
     def __hash__(self):
         return hash(tuple(sorted((k, str(v)) for k, v in self.items())))
 
-
-def widest_gap(ref_logits, served, first_pos, choose=None):
-    """Widest gap by which a served token's reference logit lies below the
-    reference's best at its position.  ``served[i]`` was produced at
-    position ``first_pos + i``; ``choose`` replaces the served tokens with
-    another model's choice (the argmax of its logits at those rows)."""
-    rows = ref_logits[first_pos: first_pos + len(served)]
-    toks = np.asarray(served) if choose is None else \
-        choose[first_pos: first_pos + len(served)].argmax(-1)
-    got = rows[np.arange(len(rows)), toks]
-    return float((rows.max(-1) - got).max())
