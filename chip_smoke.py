@@ -226,24 +226,25 @@ def count_custom_calls(eng, cfg, seed: int = SEED):
     ``tpu_custom_call`` ops in each.  Returns {program: count}."""
     import jax.numpy as jnp
 
-    from repro.models import transformer as T
-
     ex = eng._exec
     prompt = jnp.asarray(make_prompts(cfg, [8], seed)[0][None])
     _, state, _ = ex.prefill(prompt, 16)
     pstate = ex.init_pool_state()
     tok = prompt[:, -1:]
     lp, kind = ex._layer_p[0], ex.kinds[0]
-    st_l = T.decode_state_layer(state, cfg, 0)
+    where, i, per = ex._state_at[0]
     lm = jnp.asarray(0, jnp.int32)
     x = ex._jit_embed(ex.params, tok)
-    x2, _, h2 = ex._mixer_blk(kind)(lp, x, st_l, state["pos"], None, None)
+    # lowered before the call: the mixer program donates the state tree
+    mixer = ex._mixer_blk(kind).lower(lp, x, state[where][i], per,
+                                      state["pos"], None, None)
+    x2, _, h2 = ex._mixer_blk(kind)(lp, x, state[where][i], per,
+                                    state["pos"], None, None)
     _, _, info = ex._moe_blk()(lp, x2, h2, ex.store, pstate, lm, None)
     pstate = ex.init_pool_state()  # the MoE program donated the old one
     lowered = {
         "embed": ex._jit_embed.lower(ex.params, tok),
-        "mixer": ex._mixer_blk(kind).lower(lp, x, st_l, state["pos"],
-                                           None, None),
+        "mixer": mixer,
         "moe": ex._moe_blk().lower(lp, x2, h2, ex.store, pstate, lm, None),
         "stage": ex._stage_blk().lower(
             ex.store, pstate, jnp.asarray(1, jnp.int32),

@@ -730,10 +730,7 @@ def decode_step(params, cfg: ModelConfig, state, tokens, *,
                 # dense rings and fixed-size recurrent state (the
                 # DESIGN.md §12 "rec" plane — also taken by rec layers of
                 # a paged hybrid: their state never pages)
-                sslice = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, lidx, 0,
-                                                           keepdims=False),
-                    new_stacks[i])
+                sslice = stack_layer(new_stacks[i], lidx)
                 blk_in = sslice
                 if row is not None:
                     blk_in = jax.tree.map(
@@ -750,10 +747,7 @@ def decode_step(params, cfg: ModelConfig, state, tokens, *,
                         sslice, st)
                 elif active is not None:
                     st = _gate_rows(sslice, st)
-                new_stacks[i] = jax.tree.map(
-                    lambda a, b: jax.lax.dynamic_update_index_in_dim(
-                        a, b, lidx, 0),
-                    new_stacks[i], st)
+                new_stacks[i] = stack_set_layer(new_stacks[i], lidx, st)
             if collect_info:
                 inf_out.append(info)
         return (x, tuple(new_stacks)), \
@@ -831,32 +825,28 @@ def layer_kind(cfg: ModelConfig, layer_idx: int) -> str:
     return cfg.block_pattern[layer_idx % cfg.pattern_period]
 
 
-def decode_state_layer(state, cfg: ModelConfig, layer_idx: int):
-    """Slice one layer's decode state out of the stacked layout."""
-    period = cfg.pattern_period
-    n_scanned = cfg.n_periods * period
-    if layer_idx < n_scanned:
-        per = layer_idx // period
-        return jax.tree.map(lambda a: a[per], state["stack"][layer_idx % period])
-    return state["tail"][layer_idx - n_scanned]
+def stack_layer(stack, per):
+    """One layer's decode state out of a layer-stacked state tree, inside
+    a program: every leaf indexed at ``per`` (a traced int32 period
+    index, so one program serves every period).  ``per=None`` marks a
+    tail layer, whose state is not stacked and passes through as is."""
+    if per is None:
+        return stack
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, per, 0, keepdims=False),
+        stack)
 
 
-def set_decode_state_layer(state, cfg: ModelConfig, layer_idx: int, new):
-    """Write one layer's decode state back into the stacked layout
-    (pure: returns an updated state dict)."""
-    period = cfg.pattern_period
-    n_scanned = cfg.n_periods * period
-    out = dict(state)
-    if layer_idx < n_scanned:
-        per = layer_idx // period
-        i = layer_idx % period
-        out["stack"] = list(state["stack"])
-        out["stack"][i] = jax.tree.map(lambda a, b: a.at[per].set(b),
-                                       state["stack"][i], new)
-    else:
-        out["tail"] = list(state["tail"])
-        out["tail"][layer_idx - n_scanned] = new
-    return out
+def stack_set_layer(stack, per, new):
+    """Write one layer's decode state back into the stacked tree at
+    ``per`` — in place when the program donates ``stack``.  The inverse
+    of :func:`stack_layer` (``per=None``: ``new`` is the tail layer's
+    whole state)."""
+    if per is None:
+        return new
+    return jax.tree.map(
+        lambda a, b: jax.lax.dynamic_update_index_in_dim(a, b, per, 0),
+        stack, new)
 
 
 def embed_tokens(params, cfg: ModelConfig, tokens):

@@ -86,9 +86,9 @@ STEP_KEYS = frozenset({
 # route_fetch_ns), ``programs`` the jitted calls, ``fetches`` the
 # blocking device->host fetches.  The plain plane runs one program a
 # step; the packed planes run per-layer programs (the pipelined plane
-# splits each MoE block into mixer / MoE / speculative staging) around
-# the eager per-layer state slice and write-back, and prefill chunks run
-# mixer + chunk_moe on either packed plane
+# splits each MoE block into mixer / MoE / speculative staging) that
+# update the step's copy of the decode state (state_ns) in place, and
+# prefill chunks run mixer + chunk_moe on either packed plane
 _PACKED_EXEC_KEYS = frozenset({"embed_ns", "mixer_ns", "chunk_moe_ns",
                                "head_ns", "state_ns", "route_fetch_ns",
                                "programs", "fetches"})

@@ -12,9 +12,10 @@ directly.  Two process tracks:
   ``tid=1`` one span per executor dispatch site — ``exec.embed``,
   ``exec.mixer``, ``exec.moe``, ``exec.stage``, ``exec.block``,
   ``exec.chunk_moe``, ``exec.head`` (a jitted call with its argument
-  preparation), ``exec.state`` (a layer's state slice or write-back),
-  ``engine.route_fetch`` (the step's route-id fetch), ``exec.dispatch``
-  (the plain plane's one program), with ``{"layer": l}`` where a layer
+  preparation), ``exec.state`` (the step's copy of the decode state,
+  which the layer programs update in place), ``engine.route_fetch``
+  (the step's route-id fetch), ``exec.dispatch`` (the plain plane's one
+  program), with ``{"layer": l}`` where a layer
   applies — each inside its step's ``dispatch`` or ``chunk`` phase;
 * ``pid=1`` **requests** — one thread per request id, carrying that
   request's lifecycle: ``submit`` instant → ``queue_wait`` span →

@@ -37,12 +37,22 @@ program is a named function (``embed``, ``head``, ``packed_mixer``,
 ``packed_chunk_moe``, ``packed_chunk_moe_ids``), so its XLA module reads
 ``jit_<name>`` in a device trace.
 
+The packed planes' layer programs (``packed_mixer``, ``packed_blk``,
+``packed_blk_plain``) take the decode state of the layer's pattern
+position whole — the layer-stacked tree — with the layer's period index
+as a traced scalar: they read their layer out of it and write it back
+inside the program, and donate the tree, so XLA updates the stack in
+place and one program serves every layer of a kind.  Tail layers (past
+the last whole period) hold unstacked state and pass it as is.  What
+they donate is the step's own copy of the layer trees, made on the
+device once a step, so a step never consumes its caller's state.
+
 With an observer attached (:meth:`Executor.set_observer`,
 ``repro.obs.ExecSpans``) every dispatch site of the packed planes sits
 in its own span: the jitted call with its argument preparation
 (``exec.embed`` / ``exec.mixer`` / ``exec.moe`` / ``exec.stage`` /
-``exec.block`` / ``exec.chunk_moe`` / ``exec.head``) and each eager
-per-layer state slice or write-back (``exec.state``).
+``exec.block`` / ``exec.chunk_moe`` / ``exec.head``) and the step's copy
+of the layer trees (``exec.state``).
 """
 from __future__ import annotations
 
@@ -118,6 +128,15 @@ class Executor:
                     self.moe_ordinal[l] = len(self.moe_ordinal)
             self._layer_p = [T.layer_params(params, cfg, l)
                              for l in range(cfg.n_layers)]
+            # where each layer's decode state lives: ("stack", pattern
+            # position, period index as a device int32 the layer
+            # programs take traced) or ("tail", tail index, None)
+            period = cfg.pattern_period
+            n_scanned = cfg.n_periods * period
+            self._state_at = [
+                ("stack", l % period, jnp.asarray(l // period, jnp.int32))
+                if l < n_scanned else ("tail", l - n_scanned, None)
+                for l in range(cfg.n_layers)]
             def embed(p, t):
                 return T.embed_tokens(p, cfg, t)
 
@@ -253,8 +272,11 @@ class Executor:
                 donate_argnums=1))
 
     # ------------------------------------------------------------------
-    # packed-plane per-kind block programs (moved from the PR-2/PR-3
-    # PackedDecoder — identical cache keys, identical programs)
+    # packed-plane per-kind block programs.  Those that touch the decode
+    # state take ``(stack, per)`` — the layer's stacked state tree and
+    # its traced period index, or a tail layer's own state and None —
+    # and return the tree with the layer written back; the tree is
+    # donated, so it is always the step's own copy (_own_state)
     def _decode_blk(self, kind: str):
         if kind not in self._blk:
             # locals only in the closures: a `self` capture would pin the
@@ -263,22 +285,28 @@ class Executor:
             fused, vectorized = self.fused, self.vectorized
             if parse_block(kind)[1] == "moe":
                 def make():
-                    def packed_blk(p, x, st, pos, store, ps, lm, routers,
-                                   act, pages):
-                        return T.decode_block_packed(
-                            p, cfg, kind, x, st, pos, store, ps, lm,
-                            routers, lookahead=spec.lookahead,
+                    def packed_blk(p, x, stack, per, pos, store, ps, lm,
+                                   routers, act, pages):
+                        x, st, ps, info = T.decode_block_packed(
+                            p, cfg, kind, x, T.stack_layer(stack, per),
+                            pos, store, ps, lm, routers,
+                            lookahead=spec.lookahead,
                             n_spec=spec.num_speculative, fused=fused,
                             active=act, vectorized=vectorized, pages=pages)
-                    return jax.jit(packed_blk, donate_argnums=(5,))
+                        return (x, T.stack_set_layer(stack, per, st), ps,
+                                info)
+                    return jax.jit(packed_blk, donate_argnums=(2, 6))
                 key = ("packed_blk", self._mode, kind)
             else:
                 def make():
-                    def packed_blk_plain(p, x, st, pos, pages, act):
-                        return T._block_decode(
-                            p, cfg, kind, x, st, pos, moe_mode="gather",
-                            pages=pages, active=act)
-                    return jax.jit(packed_blk_plain)
+                    def packed_blk_plain(p, x, stack, per, pos, pages,
+                                         act):
+                        x, st, info = T._block_decode(
+                            p, cfg, kind, x, T.stack_layer(stack, per),
+                            pos, moe_mode="gather", pages=pages,
+                            active=act)
+                        return x, T.stack_set_layer(stack, per, st), info
+                    return jax.jit(packed_blk_plain, donate_argnums=(2,))
                 # a non-MoE block's program depends only on (cfg, kind) —
                 # identical across offload modes
                 key = ("packed_blk_plain", cfg, kind)
@@ -290,11 +318,14 @@ class Executor:
         if key not in self._blk:
             cfg = self.cfg
 
-            def packed_mixer(p, x, st, pos, pages, act):
-                return T.decode_block_packed_mixer(
-                    p, cfg, kind, x, st, pos, pages=pages, active=act)
-            self._blk[key] = T.cached_jit(("packed_mixer", cfg, kind),
-                                          lambda: jax.jit(packed_mixer))
+            def packed_mixer(p, x, stack, per, pos, pages, act):
+                x, st, h2 = T.decode_block_packed_mixer(
+                    p, cfg, kind, x, T.stack_layer(stack, per), pos,
+                    pages=pages, active=act)
+                return x, T.stack_set_layer(stack, per, st), h2
+            self._blk[key] = T.cached_jit(
+                ("packed_mixer", cfg, kind),
+                lambda: jax.jit(packed_mixer, donate_argnums=(2,)))
         return self._blk[key]
 
     def _moe_blk(self):
@@ -388,23 +419,19 @@ class Executor:
             obs.end(1)
         return logits
 
-    def _state_layer(self, state, l: int):
+    def _own_state(self, state):
+        """The step's own copy of ``state``'s layer trees (``stack``,
+        ``tail``), made on the device in one call: the layer programs
+        donate it and update it in place, and the caller's state stays
+        valid."""
         obs = self._obs
         if obs is not None:
-            obs.begin("exec.state", l)
-        st_l = T.decode_state_layer(state, self.cfg, l)
+            obs.begin("exec.state")
+        held = jax.device_put({"stack": state["stack"],
+                               "tail": state["tail"]}, may_alias=False)
         if obs is not None:
             obs.end()
-        return st_l
-
-    def _set_state_layer(self, state, l: int, st_l):
-        obs = self._obs
-        if obs is not None:
-            obs.begin("exec.state", l)
-        state = T.set_decode_state_layer(state, self.cfg, l, st_l)
-        if obs is not None:
-            obs.end()
-        return state
+        return held
 
     # ------------------------------------------------------------------
     def decode(self, state, tokens, pstate=None, active=None, *,
@@ -425,6 +452,9 @@ class Executor:
         On paged-KV states (``"pages"`` in state) ``active`` also gates
         KV writes and per-row ``pos`` advance (DESIGN.md §9): frozen
         rows are idle slots or chunked admissions mid-fill.
+
+        ``state`` stays valid: packed planes update a copy of it in
+        place (:meth:`_own_state`).
         """
         obs = self._obs
         if not self.packed:
@@ -454,14 +484,16 @@ class Executor:
         blk_site = "exec.mixer" if self.pipelined else "exec.block"
         moe_site = "exec.moe" if self.pipelined else "exec.block"
         route_ids = []
+        held = self._own_state(state)
         for l, kind in enumerate(self.kinds):
-            st_l = self._state_layer(state, l)
+            where, i, per = self._state_at[l]
+            st = held[where][i]
             lp = self._layer_p[l]
             if l not in self.moe_ordinal:
                 if obs is not None:
                     obs.begin(blk_site, l)
-                x, st_l, _ = self._decode_blk(kind)(
-                    lp, x, st_l, pos, pages, active)
+                x, st, _ = self._decode_blk(kind)(
+                    lp, x, st, per, pos, pages, active)
                 if obs is not None:
                     obs.end(1)
             elif self._fetch_faulted():
@@ -471,8 +503,8 @@ class Executor:
                 # traffic, no speculative staging
                 if obs is not None:
                     obs.begin(blk_site, l)
-                x, st_l, h2 = self._mixer_blk(kind)(
-                    lp, x, st_l, pos, pages, active)
+                x, st, h2 = self._mixer_blk(kind)(
+                    lp, x, st, per, pos, pages, active)
                 if obs is not None:
                     obs.end(1)
                     obs.begin(moe_site, l)
@@ -484,8 +516,8 @@ class Executor:
             elif self.pipelined:
                 if obs is not None:
                     obs.begin("exec.mixer", l)
-                x, st_l, h2 = self._mixer_blk(kind)(
-                    lp, x, st_l, pos, pages, active)
+                x, st, h2 = self._mixer_blk(kind)(
+                    lp, x, st, per, pos, pages, active)
                 if obs is not None:
                     obs.end(1)
                     obs.begin("exec.moe", l)
@@ -508,13 +540,13 @@ class Executor:
                 if obs is not None:
                     obs.begin("exec.block", l)
                 lm = jnp.asarray(self.moe_ordinal[l], jnp.int32)
-                x, st_l, pstate, info = self._decode_blk(kind)(
-                    lp, x, st_l, pos, self.store, pstate, lm,
+                x, st, pstate, info = self._decode_blk(kind)(
+                    lp, x, st, per, pos, self.store, pstate, lm,
                     self.routers, active, pages)
                 if obs is not None:
                     obs.end(1)
                 route_ids.append(info["route"]["ids"])
-            state = self._set_state_layer(state, l, st_l)
+            held[where][i] = st
         logits = self._head(x)
         # decode is the C=1 case of a chunk; a C=k+1 verify chunk
         # (speculative decoding, DESIGN.md §11) advances by its width
@@ -523,7 +555,7 @@ class Executor:
             pos = pos + jnp.where(active, C, 0).astype(pos.dtype)
         else:
             pos = pos + C
-        state = dict(state, pos=pos)
+        state = dict(state, **held, pos=pos)
         return logits, state, pstate, route_ids
 
     def decode_sampled(self, state, tokens, *, collect_info: bool,
@@ -548,14 +580,16 @@ class Executor:
         whole block otherwise.  Returns (x, state)."""
         obs = self._obs
         blk_site = "exec.mixer" if self.pipelined else "exec.block"
+        held = self._own_state(state)
         for l, kind in enumerate(self.kinds):
-            st_l = self._state_layer(state, l)
+            where, i, per = self._state_at[l]
+            st = held[where][i]
             lp = self._layer_p[l]
             if l in self.moe_ordinal:
                 if obs is not None:
                     obs.begin("exec.mixer", l)
-                x, st_l, h2 = self._mixer_blk(kind)(
-                    lp, x, st_l, pos, pages, None)
+                x, st, h2 = self._mixer_blk(kind)(
+                    lp, x, st, per, pos, pages, None)
                 if obs is not None:
                     obs.end(1)
                     obs.begin("exec.chunk_moe", l)
@@ -564,12 +598,12 @@ class Executor:
             else:
                 if obs is not None:
                     obs.begin(blk_site, l)
-                x, st_l, _ = self._decode_blk(kind)(
-                    lp, x, st_l, pos, pages, None)
+                x, st, _ = self._decode_blk(kind)(
+                    lp, x, st, per, pos, pages, None)
             if obs is not None:
                 obs.end(1)
-            state = self._set_state_layer(state, l, st_l)
-        return x, state
+            held[where][i] = st
+        return x, dict(state, **held)
 
     def prefill_chunk(self, state, tokens, pstate=None):
         """Process prompt chunk ``tokens`` (B, C) at the rows' current

@@ -312,8 +312,7 @@ def test_exec_spans_nest_in_their_step_phase(tiny_moe_cfg, tiny_moe_params):
         if name == "exec.chunk_moe":
             assert parent == ["chunk"]
         assert ("layer" in args) == (name in (
-            "exec.state", "exec.mixer", "exec.moe", "exec.stage",
-            "exec.chunk_moe"))
+            "exec.mixer", "exec.moe", "exec.stage", "exec.chunk_moe"))
     # sites never overlap: their sum is the part of dispatch they cover
     ordered = sorted(sites)
     assert all(a[1] <= b[0] for a, b in zip(ordered, ordered[1:]))
@@ -337,6 +336,10 @@ def test_step_span_counts_programs_and_fetches(tiny_moe_cfg,
             stages = sum(1 for s, e, n, _ in sites
                          if n == "exec.stage" and lo <= s and e <= hi)
             assert stages == n_moe - 1  # lookahead 1
+            # one copy of the decode state a step, not a slice and a
+            # write-back a layer: the layer programs update it in place
+            assert sum(1 for s, e, n, _ in sites
+                       if n == "exec.state" and lo <= s and e <= hi) == 1
             assert args["programs"] == 2 + 2 * n_moe + stages
             assert args["fetches"] == n_moe  # one route-id array a layer
     assert decode_steps == 2 * 4

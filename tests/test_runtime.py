@@ -263,6 +263,7 @@ def test_executor_block_programs_compile_once(tiny_moe_cfg,
     cfg, params = tiny_moe_cfg, tiny_moe_params
     spec, exec_params, store, _ = packed_setup
     prompt = _prompt(cfg, 6, seed=9)
+    T.cached_jit_clear()  # count only the shapes this test compiles
 
     def exercise(make):
         ex = make()
@@ -288,6 +289,17 @@ def test_executor_block_programs_compile_once(tiny_moe_cfg,
         after = T.cached_jit_stats()["builds"]
         assert after == before, \
             f"identical executor mode rebuilt {after - before} programs"
+        ex = make()
+        if ex.packed:
+            # one compiled layer program per kind and chunk width across
+            # all layers: the layer's period index rides in traced.  The
+            # mixer serves the prompt chunk and (pipelined) decode; the
+            # vectorized plane decodes through its block program
+            kind, = set(ex.kinds)
+            if ex.pipelined:
+                assert ex._mixer_blk(kind)._cache_size() == 2
+            else:
+                assert ex._decode_blk(kind)._cache_size() == 1
 
 
 def test_cached_jit_stats_and_clear():

@@ -121,7 +121,10 @@ def test_granite_packed_decode_layer_compiles_and_fits(one_chip):
     """One whole packed decode layer of granite-moe-1b-a400m (mixer over
     paged KV + MoE served from the full 24-layer 3-bit expert store and
     its buffer pool) for a 4-slot decode step, as one program; its
-    arguments and temporaries fit one chip's HBM."""
+    arguments and temporaries fit one chip's HBM.  As the executor's
+    layer programs do, it reads its layer out of the 24-layer stacked KV
+    pool at a traced index and writes it back into the donated stack,
+    which the compiler updates in place (aliased to the output)."""
     from repro.core import expert_pool as EP
     from repro.models import layers as L
     from repro.models import transformer as T
@@ -133,23 +136,31 @@ def test_granite_packed_decode_layer_compiles_and_fits(one_chip):
     store = jax.eval_shape(lambda p: EP.build_store(p, cfg, spec), params)
     pstate = jax.eval_shape(lambda s: EP.init_pool_state(s, spec), store)
     lp = jax.eval_shape(lambda p: T.layer_params(p, cfg, 0), params)
-    st = jax.eval_shape(lambda: {"kv": L.init_paged_attn_cache(
-        cfg, pages, page)})
+    stack = jax.eval_shape(lambda: T.init_decode_state(
+        cfg, B, 0, kv_pages=pages, kv_page=page,
+        kv_max_pages=max_pages)["stack"][0])
     kind = cfg.block_pattern[0]
 
-    def layer(p, x, st, pos, pg, act, store, ps, lm):
-        x, st, h2 = T.decode_block_packed_mixer(p, cfg, kind, x, st, pos,
-                                                 pages=pg, active=act)
+    def layer(p, x, stack, per, pos, pg, act, store, ps, lm):
+        x, st, h2 = T.decode_block_packed_mixer(
+            p, cfg, kind, x, T.stack_layer(stack, per), pos, pages=pg,
+            active=act)
         x, ps, _ = T.decode_block_packed_moe(p, cfg, x, h2, store, ps, lm,
                                              active=act)
-        return x, st, ps
+        return x, T.stack_set_layer(stack, per, st), ps
 
     sds = jax.ShapeDtypeStruct
     args = jax.tree.map(
         lambda a: sds(a.shape, a.dtype, sharding=one_chip),
-        (lp, sds((B, 1, cfg.d_model), jnp.bfloat16), st,
-         sds((B,), jnp.int32), sds((B, max_pages), jnp.int32),
-         sds((B,), jnp.bool_), store, pstate, sds((), jnp.int32)))
-    c = jax.jit(layer, donate_argnums=(7,)).lower(*args).compile()
+        (lp, sds((B, 1, cfg.d_model), jnp.bfloat16), stack,
+         sds((), jnp.int32), sds((B,), jnp.int32),
+         sds((B, max_pages), jnp.int32), sds((B,), jnp.bool_), store,
+         pstate, sds((), jnp.int32)))
+    c = jax.jit(layer, donate_argnums=(2, 8)).lower(*args).compile()
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+    assert nbytes(stack) > 10 * 1024 ** 2  # 24 layers of K and V pages
+    # both donated trees, the KV stack and the pool, are updated in place
+    assert mem.alias_size_in_bytes >= nbytes(stack) + nbytes(pstate)
