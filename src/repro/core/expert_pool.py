@@ -39,6 +39,9 @@ cannot drift apart.
 """
 from __future__ import annotations
 
+import contextlib
+import math
+from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -94,19 +97,73 @@ class PoolState(NamedTuple):
 
 # ----------------------------------------------------------------------
 # construction
-def _reshape_leading(qt: hqq.QTensor, lead: Tuple[int, ...]) -> hqq.QTensor:
-    """(P*E, ...) leaves -> ``lead``-shaped leading axes."""
-    r = lambda a: a.reshape(lead + a.shape[1:])
-    meta = None if qt.meta is None else {k: r(v) for k, v in qt.meta.items()}
-    return hqq.QTensor(r(qt.packed), r(qt.scale), r(qt.zero), meta,
-                       qt.bits, qt.group_size, lead + qt.shape[1:])
+# Bound on the float32 copy of one slab of an expert matrix stack while
+# the store is built.  The seeded bfloat16 weights are still on the device
+# then: at Mixtral-8x7B's widths four layers take 12.1 GB of a v5e's
+# 16 GiB and their packed store 2.1 GB more, which leaves ~1.5 GB for a
+# slab, its bfloat16 and float32 copies and HQQ's codes and scales (HQQ's
+# program itself needs no temporaries beyond them on a v5e).  One
+# 4096 x 14336 expert matrix is 235 MB in float32, so a slab then holds
+# one; Granite's 2 MiB matrices go 128 to a slab.  A slab holds at least
+# one matrix, whatever its size.
+SLAB_F32_BYTES = 256 << 20
 
 
-def build_store(params, cfg: ModelConfig, spec: OffloadSpec) -> PackedExperts:
+@partial(jax.jit, static_argnums=2)
+def _slab(leaf, start, n):
+    """Matrices ``start .. start + n`` of a (P, E, K, N) stack as one
+    (n, K, N) array, without a copy of the whole stack."""
+    flat = leaf.reshape((-1,) + leaf.shape[-2:])
+    return jax.lax.dynamic_slice_in_dim(flat, start, n)
+
+
+@partial(jax.jit, static_argnums=0)
+def _join(lead, parts):
+    """The slabs' leaves concatenated along the matrix axis, which then
+    takes the ``lead`` axes."""
+    return jax.tree.map(
+        lambda *xs: jnp.concatenate(xs).reshape(lead + xs[0].shape[1:]),
+        *parts)
+
+
+def _quantize_stack(leaf, bits: int, span) -> hqq.QTensor:
+    """HQQ-quantize a (P, E, K, N) expert matrix stack in slabs of at most
+    ``SLAB_F32_BYTES`` of float32 along its P*E matrix axis, each slab
+    finished before the next is begun.  HQQ and its meta-quantization
+    group along K inside each matrix, so the codes are those of one call
+    on the whole stack.  ``span(start, n)``: a context around a slab's
+    quantization (``None`` or a span whose ``args`` take its ``bytes``)."""
+    lead, (K, N) = leaf.shape[:-2], leaf.shape[-2:]
+    total = math.prod(lead)
+    per = max(1, SLAB_F32_BYTES // (K * N * 4))
+    parts, qt = [], None
+    for start in range(0, total, per):
+        n = min(per, total - start)
+        with span(start, n) as sp:
+            qt = hqq.quantize(_slab(leaf, start, n), bits)
+            jax.block_until_ready(qt)
+            if sp is not None:
+                sp.args["bytes"] = hqq.nbytes(qt)
+        parts.append((qt.packed, qt.scale, qt.zero, qt.meta))
+    packed, scale, zero, meta = _join(tuple(lead), parts)
+    return hqq.QTensor(packed, scale, zero, meta, qt.bits, qt.group_size,
+                       tuple(leaf.shape))
+
+
+def build_store(params, cfg: ModelConfig, spec: OffloadSpec,
+                telemetry=None) -> PackedExperts:
     """Quantize every MoE layer's experts into the layer-major packed host
     store, bitwise the same quantization ``quantize_for_offload`` applies
     before its (oracle-only) dequantization — the packed-execution parity
     invariant rests on this.
+
+    Each matrix stack is quantized in slabs of at most ``SLAB_F32_BYTES``
+    of float32 (:func:`_quantize_stack`): at Mixtral-8x7B's widths the
+    float32 copy of a whole stack would not fit on the chip beside the
+    weights it is built from.  With ``telemetry`` each slab is an
+    ``offload.build`` span, with args ``matrix``, ``layer`` (the layer
+    it starts in), ``experts`` (the matrices in it) and ``bytes`` (their
+    packed size).
     """
     assert cfg.moe is not None, "packed store targets MoE architectures"
     if any(parse_block(k)[1] == "moe" for k in cfg.tail_kinds()):
@@ -117,21 +174,26 @@ def build_store(params, cfg: ModelConfig, spec: OffloadSpec) -> PackedExperts:
     gs = hqq.PAPER_SCHEMES[spec.expert_bits]["group_size"]
     P = cfg.n_periods
     per_pos = []
-    for p in pos:
+    for j, p in enumerate(pos):
         leafs = params["stack"][p]["moe"]["experts"]
         mats = {}
         for name in EXPERT_MATS:
             leaf = leafs[name]          # (P, E, K, N)
-            K = leaf.shape[-2]
+            K, E = leaf.shape[-2], leaf.shape[1]
             if K % gs:
                 raise ValueError(
                     f"packed offloading needs expert contraction dims "
                     f"divisible by the {spec.expert_bits}-bit group size "
                     f"{gs}; got {name} with K={K}")
-            # identical call shape to quantize_for_offload's quant_leaf
-            mat = leaf.reshape(-1, *leaf.shape[-2:])
-            qt = hqq.quantize(mat, spec.expert_bits)
-            mats[name] = _reshape_leading(qt, leaf.shape[:2])  # (P, E, ...)
+
+            def span(start, n, name=name, E=E, j=j):
+                if telemetry is None:
+                    return contextlib.nullcontext()
+                # execution order is period-major over the MoE positions
+                return telemetry.span("offload.build", matrix=name,
+                                      layer=(start // E) * len(pos) + j,
+                                      experts=n)
+            mats[name] = _quantize_stack(leaf, spec.expert_bits, span)
         per_pos.append(mats)
 
     def layer_major(name):

@@ -36,6 +36,7 @@ the eviction sequence — in
 from __future__ import annotations
 
 import dataclasses
+import time
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
@@ -161,7 +162,7 @@ class ExpertUsageTracker:
 
 # ----------------------------------------------------------------------
 def quantize_for_offload(params, cfg: ModelConfig, spec: OffloadSpec, *,
-                         pack_experts: bool = False):
+                         pack_experts: bool = False, telemetry=None):
     """Mixed quantization of the model (paper §3.3): experts at
     ``spec.expert_bits``, attention/shared weights at ``spec.attn_bits``;
     embeddings / router / norms stay 16-bit.
@@ -179,11 +180,16 @@ def quantize_for_offload(params, cfg: ModelConfig, spec: OffloadSpec, *,
     zero-size placeholders for the expert stacks — the packed engine
     below computes MoE straight from the store/pool, so no dense expert
     tensor exists to materialize.  ``size_report["experts"]`` is then the
-    measured store size.
+    measured store size.  The store is built in bounded slabs of each
+    expert matrix stack, so that the build needs little device memory
+    beside the weights it reads (at Mixtral-8x7B's widths the float32
+    copy of one whole stack would not fit on the chip); ``telemetry``
+    receives one ``offload.build`` span per slab.
     """
     qsizes = {"experts": 0, "attn": 0, "fp16": 0}
     dtype = jnp.dtype(cfg.dtype)
-    store = EP.build_store(params, cfg, spec) if pack_experts else None
+    store = (EP.build_store(params, cfg, spec, telemetry)
+             if pack_experts else None)
 
     def quant_leaf(path, leaf, bits):
         if leaf.ndim < 2:
@@ -277,13 +283,21 @@ class OffloadEngine:
         self.store = None
         self._decoder = None
         self._last_pool_state = None
+        from repro.obs import Telemetry, jit_cache_metrics
+        self.obs = telemetry if telemetry is not None else Telemetry.off()
+        self.build_s = 0.0  # seconds spent quantizing and packing
         if quantized:
+            t0 = time.perf_counter()
             if self.packed:
                 params, self.size_report, self.store = quantize_for_offload(
-                    params, cfg, self.spec, pack_experts=True)
+                    params, cfg, self.spec, pack_experts=True,
+                    telemetry=self.obs)
             else:
                 params, self.size_report = quantize_for_offload(
                     params, cfg, self.spec)
+            jax.block_until_ready((params, self.store))
+            self.build_s = time.perf_counter() - t0
+        self.store_bytes = 0 if self.store is None else self.store.nbytes()
         self.params = params
         self.routers = stacked_routers(params, cfg)  # (L_moe, D, E)
         self.n_moe_layers = self.routers.shape[0]
@@ -307,8 +321,6 @@ class OffloadEngine:
         # telemetry plane (DESIGN.md §10): cumulative transfer accounting
         # feeds the offload collector; each generate() traces its
         # prefill/decode spans
-        from repro.obs import Telemetry, jit_cache_metrics
-        self.obs = telemetry if telemetry is not None else Telemetry.off()
         self.last_stats: Optional[OffloadStats] = None
         self._cum = OffloadStats(expert_bytes=self.expert_bytes)
         self.obs.registry.register_collector("offload", self._offload_metrics)
@@ -341,7 +353,8 @@ class OffloadEngine:
         return {"hits": c.hits, "spec_hits": c.spec_hits,
                 "demand_loads": c.demand_loads, "spec_loads": c.spec_loads,
                 "bytes_h2d": c.bytes_h2d,
-                "bytes_per_token": c.bytes_h2d / max(1, c.n_tokens)}
+                "bytes_per_token": c.bytes_h2d / max(1, c.n_tokens),
+                "store_bytes": self.store_bytes, "build_s": self.build_s}
 
     def _record_generate(self, stats: OffloadStats) -> None:
         """Fold one generate()'s measured stats into the telemetry plane."""

@@ -12,7 +12,9 @@ Metric names are ``namespace.key``.  Namespaces:
 * ``engine``   — step/token/request lifecycle counts (collector-backed).
 * ``kv``       — KV occupancy, from the slot/page manager (collector).
 * ``offload``  — expert-streaming traffic counters (collector; only on
-  offloaded engines — the counters the engine already fetches).
+  offloaded engines — the counters the engine already fetches), and the
+  packed store's size (``store_bytes``) and the seconds its build took
+  (``build_s``; 0 for an engine that quantizes nothing).
 * ``jit``      — process-wide engine-executable cache
   (``transformer.cached_jit_stats``, minus the unserializable keys).
 * ``step``     — per-engine-step phase breakdown (declared counters +
@@ -69,7 +71,7 @@ KV_KEYS_PAGED = frozenset({
 
 OFFLOAD_KEYS = frozenset({
     "hits", "spec_hits", "demand_loads", "spec_loads", "bytes_h2d",
-    "bytes_per_token",
+    "bytes_per_token", "store_bytes", "build_s",
 })
 
 JIT_KEYS = frozenset({"builds", "hits", "entries"})

@@ -1431,7 +1431,9 @@ class ContinuousEngine:
         return {"hits": hits, "spec_hits": spec_hits,
                 "demand_loads": demand, "spec_loads": spec,
                 "bytes_h2d": bytes_h2d,
-                "bytes_per_token": bytes_h2d / max(1, emitted)}
+                "bytes_per_token": bytes_h2d / max(1, emitted),
+                "store_bytes": self.offload.store_bytes,
+                "build_s": self.offload.build_s}
 
     def _prefix_metrics(self) -> Dict[str, float]:
         out = {"lookups": self._prefix.lookups,

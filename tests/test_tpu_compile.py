@@ -164,3 +164,58 @@ def test_granite_packed_decode_layer_compiles_and_fits(one_chip):
     assert nbytes(stack) > 10 * 1024 ** 2  # 24 layers of K and V pages
     # both donated trees, the KV stack and the pool, are updated in place
     assert mem.alias_size_in_bytes >= nbytes(stack) + nbytes(pstate)
+
+
+def test_mixtral_store_slab_and_prompt_chunk_fit(one_chip):
+    """Mixtral-8x7B at its widths and four layers (``mixtral-offload.l4``):
+    a store slab is one 4096 x 14336 expert matrix, and HQQ's program on
+    it needs no device memory beyond its float32 input and its codes;
+    the prompt chunk's MoE program at the served 2 tokens fits beside
+    the whole store and the pool, while 4 tokens double its float32
+    temporaries (why the configuration serves chunks of 2)."""
+    from functools import partial
+
+    from repro.core import expert_pool as EP
+    from repro.core.offload_engine import quantize_for_offload
+    from repro.models import moe as M
+    from repro.models import transformer as T
+    from repro.quant import hqq
+
+    S = _specs(one_chip)
+    for K, N in ((4096, 14336), (14336, 4096)):
+        assert EP.SLAB_F32_BYTES // (K * N * 4) == 1
+        c = _compile(partial(hqq._quantize_groups.__wrapped__, bits=2,
+                             group_size=16, iters=20),
+                     S((1, K // 16, 16, N), jnp.float32))
+        mem = c.memory_analysis()
+        assert mem.temp_size_in_bytes <= K * N  # codes and scales at most
+
+    cfg = get_config("mixtral-offload").replace(
+        n_layers=4, block_pattern=("attn+moe",), sliding_window=None)
+    params = jax.eval_shape(lambda: T.init_model(jax.random.key(0), cfg))
+    ex, store = jax.eval_shape(lambda p: (lambda r: (r[0], r[2]))(
+        quantize_for_offload(p, cfg, cfg.offload, pack_experts=True)),
+        params)
+    pstate = jax.eval_shape(lambda s: EP.init_pool_state(s, cfg.offload),
+                            store)
+    lp = jax.eval_shape(lambda p: T.layer_params(p, cfg, 0), ex)
+
+    def chunk_moe(p, x, h2, store, lm):  # the executor's packed_chunk_moe
+        B, C, D = h2.shape
+        y, _ = M.moe_apply_packed_stream(p["moe"], cfg, h2.reshape(B * C, D),
+                                         store, lm, fused=True)
+        return x + y.reshape(B, C, D)
+
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+    temps = {}
+    for C in (2, 4):
+        x = S((1, C, cfg.d_model), jnp.bfloat16)
+        args = jax.tree.map(lambda a: S(a.shape, a.dtype),
+                            (lp, x, x, store, S((), jnp.int32)))
+        mem = _compile(chunk_moe, *args).memory_analysis()
+        temps[C] = mem.temp_size_in_bytes
+        if C == 2:
+            assert mem.argument_size_in_bytes + temps[C] + nbytes(pstate) \
+                < HBM_BYTES - 2 * 1024 ** 3
+    assert temps[4] > 1.9 * temps[2]
